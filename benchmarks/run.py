@@ -21,6 +21,9 @@ MODULES = [
 
 def main() -> None:
     import importlib
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for mod_name in MODULES:

@@ -131,19 +131,20 @@ def calibrated_speeds(n_in: int = 4096, n_out: int = 4096,
     """Measure this host's v_cpu / v_pin; take v_com from the hardware model.
 
     Returns a dict compatible with :func:`repro.core.alpha.decide` kwargs.
-    There is no accelerator in this container, so v_gpu/v_com come from the
-    hardware spec (TPU_V5E by default).
+    The link and the device are not probed yet, so v_gpu/v_com come from
+    the spec of the device JAX runs on (:func:`repro.core.hw.spec_for_device`).
     """
-    from repro.core.hw import TPU_V5E
+    from repro.core.hw import spec_for_device
 
+    hw = spec_for_device()
     nbytes = n_in * n_out * 4
     t_cpu = measure_host_linear(n_in, n_out)
     t_pin = measure_staging_copy(nbytes)
     return {
         "v_cpu": nbytes / max(t_cpu, 1e-9),
         "v_pin": nbytes / max(t_pin, 1e-9),
-        "v_com": link_bw if link_bw is not None else TPU_V5E.link_bw,
-        "v_gpu": TPU_V5E.accel_mem_bw,
+        "v_com": link_bw if link_bw is not None else hw.link_bw,
+        "v_gpu": hw.accel_mem_bw,
     }
 
 

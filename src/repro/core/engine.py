@@ -27,11 +27,12 @@ Executes the linear modules of a model under a per-module placement plan:
 
 Four real executors provide the four streams of the paper's Fig. 5c: the
 host GEMM pool, the manager's pin thread, the transfer thread, and the
-device queue (JAX async dispatch).  On this CPU-only container the "device"
-is jax's CpuDevice, so wall-clock overlap is bounded by the single core, but
-the *mechanism* — ordering, ring reuse, prefetch, correctness — is identical
-to the TPU deployment, and per-stream busy seconds are measured for the
-Table-2 style breakdown.
+device queue (JAX async dispatch).  On a TPU the device is the chip and
+``device_put`` is a host-to-device DMA.  On the CPU backend (tests) the
+"device" is jax's CpuDevice, which shares the host's cores and
+zero-copies ``device_put``; the mechanism — ordering, ring reuse,
+prefetch, correctness — is the same, and per-stream busy seconds are
+measured for the Table-2 style breakdown either way.
 """
 
 from __future__ import annotations

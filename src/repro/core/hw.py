@@ -16,8 +16,12 @@ Two concrete systems are modeled:
     @2.30GHz + PCIe 30 GB/s, Table 1).  Used by the paper-reproduction
     benchmarks so Fig. 8 / Table 2 / Table 3 are comparable to the paper.
   * ``TPU_V5E``    — the TPU-native target this framework is built for.
-    Accelerator constants match the roofline constants used in
-    EXPERIMENTS.md (197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI).
+    Accelerator constants are Google Cloud's published v5e figures
+    (197 TFLOP/s bf16, 819 GB/s HBM); the host and link constants are
+    not measured yet.
+
+:func:`spec_for_device` picks the spec from the device JAX reports, so
+a plan never silently uses another rig's constants.
 
 Decode-phase (batch≈1) linear layers are memory-bandwidth bound on every
 resource, so "speed" for the alpha law is expressed in *parameter bytes per
@@ -113,10 +117,10 @@ PAPER_A10 = HardwareSpec(
 )
 
 # ---------------------------------------------------------------------------
-# TPU v5e host — the deployment target.  Roofline constants per the task
-# sheet: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s per ICI link.  Host side:
-# a v5e host exposes ~PCIe gen4-class DMA to its chips and a server-class
-# DRAM subsystem.
+# TPU v5e host — the deployment target.  Chip constants: 197 TFLOP/s bf16,
+# 819 GB/s HBM, 16 GB (Google Cloud, "TPU v5e").  Host side (CPU GEMM,
+# DRAM, PCIe DMA, pin) is an estimate of a PCIe gen4-class server host,
+# not a measurement.
 # ---------------------------------------------------------------------------
 TPU_V5E = HardwareSpec(
     name="tpu-v5e-host",
@@ -137,3 +141,38 @@ TPU_V5E = HardwareSpec(
 HARDWARE = {h.name: h for h in (PAPER_A10, TPU_V5E)}
 HARDWARE["a10"] = PAPER_A10
 HARDWARE["v5e"] = TPU_V5E
+
+# The node each accelerator plans with, keyed by ``jax.Device.device_kind``.
+# The CPU backend (tests, rehearsal) plans as if for the v5e host; any other
+# device kind has no spec and is refused rather than planned with a guess.
+DEVICE_KIND_SPECS = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+    "cpu": TPU_V5E,
+}
+
+
+def spec_for_device(device=None) -> HardwareSpec:
+    """The :class:`HardwareSpec` for ``device`` (default: the first JAX
+    device), looked up by its ``device_kind``; raises for a kind that is
+    not in :data:`DEVICE_KIND_SPECS`."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    try:
+        return DEVICE_KIND_SPECS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware spec for {device.platform} device kind "
+            f"{device.device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_SPECS)}") from None
+
+
+def describe_plan_hw(hw: HardwareSpec, device=None) -> str:
+    """One line naming the spec a plan used and the device it ran on, so a
+    plan made on the CPU backend is never read as a chip plan."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return (f"plan hw={hw.name} for device platform={device.platform} "
+            f"kind={device.device_kind}")

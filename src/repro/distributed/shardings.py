@@ -50,17 +50,6 @@ SP_OVERLAY = {
 }
 
 
-def _mesh_axis_names() -> Tuple[str, ...]:
-    m = getattr(jax.sharding, "get_abstract_mesh", None)
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and mesh.axis_names:
-            return tuple(mesh.axis_names)
-    except Exception:
-        pass
-    return ()
-
-
 @dataclasses.dataclass
 class ShardingRules:
     """Maps logical axes to mesh axes and applies activation constraints.

@@ -1,8 +1,11 @@
 """Dispatch layer: jit'd public kernel API.
 
-On TPU the Pallas kernels are compiled natively; on CPU (this container)
-the pure-jnp references are the compiled path and the kernels run under
-``interpret=True`` only in tests.  ``force`` overrides for benchmarking:
+On a TPU backend the Pallas kernels are compiled natively; on the CPU
+backend (tests and rehearsals) the pure-jnp references are the compiled
+path and the kernels run under ``interpret=True`` only in tests.  A run
+that must be on the chip checks ``_mode() == "pallas"`` itself
+(``chip_smoke.py``), since a CPU run would otherwise pass on the
+references.  ``set_mode`` overrides the choice:
 
     repro_kernels.set_mode("pallas")      # TPU production
     repro_kernels.set_mode("ref")         # CPU/XLA fallback

@@ -71,27 +71,42 @@ def _q8_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k):
                       ).astype(o_ref.dtype)
 
 
+def _round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
+
+
 def q8_matmul(x: jax.Array, q: jax.Array, scale: jax.Array, *,
               block_m: int = 128, block_n: int = 128, block_k: int = 128,
               interpret: bool = False) -> jax.Array:
-    """x (M, K) fp  @  dequant(q (K, N) int8, scale (N,)) -> (M, N) fp."""
+    """x (M, K) fp  @  dequant(q (K, N) int8, scale (N,)) -> (M, N) fp.
+
+    Any M: rows are padded up to a multiple of the row block (decode runs
+    at M = batch, prefill at M = batch * prompt) and sliced off after.
+    The scale travels as a (1, N) row with (1, bn) blocks — Mosaic has no
+    layout for a 1-D block of a 1-D operand.
+    """
     m, k = x.shape
     k2, n = q.shape
     assert k == k2 and scale.shape == (n,)
-    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
-    assert m % bm == 0 and n % bn == 0 and k % bk == 0
+    bm = min(block_m, _round_up(m, 8))
+    bn, bk = min(block_n, n), min(block_k, k)
+    assert n % bn == 0 and k % bk == 0
+    m_pad = _round_up(m, bm)
+    if m_pad != m:
+        x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
     n_k = k // bk
     kernel = functools.partial(_q8_kernel, n_k=n_k)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(m // bm, n // bn, n_k),
+        grid=(m_pad // bm, n // bn, n_k),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, s: (i, s)),
             pl.BlockSpec((bk, bn), lambda i, j, s: (s, j)),
-            pl.BlockSpec((bn,), lambda i, j, s: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, s: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, q, scale)
+    )(x, q, scale.reshape(1, n))
+    return out[:m] if m_pad != m else out

@@ -89,8 +89,9 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--stream", action="store_true",
                     help="stream the first request token by token")
-    ap.add_argument("--hw", default="a10", help="hardware model for the "
-                    "alpha law (a10 | v5e)")
+    ap.add_argument("--hw", default=None, help="hardware model for the "
+                    "alpha law (a10 | v5e); default: the spec of the device "
+                    "JAX runs on (core/hw.py DEVICE_KIND_SPECS)")
     ap.add_argument("--wstream", choices=("fp", "q8"), default="fp",
                     help="wire format of streamed weights in the offload "
                     "modes: fp streams shards as-is, q8 streams int8 + "
@@ -121,11 +122,13 @@ def main() -> None:
     import numpy as np
 
     from repro.configs import get_config, reduced
-    from repro.core.hw import HARDWARE
+    from repro.core.hw import HARDWARE, describe_plan_hw, spec_for_device
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.serving.api import LLM
     from repro.serving.sampling import SamplingParams
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -156,7 +159,9 @@ def main() -> None:
     if args.mode in ("offload", "batch-offload"):
         from repro.serving.backends import HeteGenBackend, enumerate_linears
         total = sum(s.nbytes for s in enumerate_linears(cfg))
-        backend = HeteGenBackend(cfg, params, hw=HARDWARE[args.hw],
+        hw = spec_for_device() if args.hw is None else HARDWARE[args.hw]
+        print(f"  {describe_plan_hw(hw)}")
+        backend = HeteGenBackend(cfg, params, hw=hw,
                                  batch=slots,
                                  budget_bytes=args.budget_frac * total,
                                  wstream=args.wstream)

@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core.alpha import resolve_phase_tokens
 from repro.core.engine import HeteGenEngine, ModulePlan, StreamStats
-from repro.core.hw import HardwareSpec, TPU_V5E
+from repro.core.hw import HardwareSpec, spec_for_device
 from repro.core.policy import LinearSpec, PolicyResult, build_policy
 from repro.models import model as M
 from repro.models.config import ModelConfig
@@ -287,7 +287,7 @@ class HeteGenBackend:
     cache_batch_axis = 0
 
     def __init__(self, cfg: ModelConfig, params: Dict, *,
-                 hw: HardwareSpec = TPU_V5E,
+                 hw: Optional[HardwareSpec] = None,
                  budget_bytes: Optional[float] = None,
                  batch: int = 1,
                  use_alpha_benchmark: bool = True,
@@ -310,7 +310,8 @@ class HeteGenBackend:
         self._ops = M.make_backend_ops(cfg)   # jitted norms/attention/head
         self.wstream = wstream
         self.linears = enumerate_linears(cfg, wstream=wstream)
-        self.hw = hw
+        # the planning rig follows the device the engines run on
+        self.hw = hw if hw is not None else spec_for_device()
         self.budget_bytes = budget_bytes
         self.use_alpha_benchmark = use_alpha_benchmark
         self.use_module_scheduler = use_module_scheduler

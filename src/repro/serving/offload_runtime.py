@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.hw import HardwareSpec, TPU_V5E
+from repro.core.hw import HardwareSpec
 from repro.serving.backends import HeteGenBackend, enumerate_linears  # noqa: F401  (re-export)
 from repro.models.config import ModelConfig
 from repro.serving.sampling import SamplerConfig, make_sampler
@@ -51,7 +51,7 @@ class OffloadGenerator:
     """
 
     def __init__(self, cfg: ModelConfig, params: Dict, *,
-                 hw: HardwareSpec = TPU_V5E,
+                 hw: Optional[HardwareSpec] = None,
                  budget_bytes: Optional[float] = None,
                  use_alpha_benchmark: bool = True,
                  use_module_scheduler: bool = True,

@@ -56,6 +56,18 @@ def test_q8_matmul(rng):
     assert np.abs(np.asarray(got) - full).max() / np.abs(full).max() < 0.05
 
 
+@pytest.mark.parametrize("m", [4, 200])
+def test_q8_matmul_ragged_rows(rng, m):
+    """Decode (M = batch) and prefill rows that no 128-row block divides:
+    the kernel pads M and slices the pad rows back off."""
+    x, w = _rand(rng, (m, 256)), _rand(rng, (256, 384))
+    q, s = ops.quantize_weights(w)
+    got = ops.q8_matmul(x, q, s)
+    assert got.shape == (m, 384)
+    np.testing.assert_allclose(got, ref.q8_matmul(x, q, s),
+                               rtol=1e-3, atol=1e-3)
+
+
 @pytest.mark.parametrize("causal,window,softcap", [
     (True, None, None), (False, None, None),
     (True, 64, None), (True, None, 20.0)])

@@ -18,12 +18,7 @@ echo "== tier-1: static invariant lint (repro.analysis.lint) =="
 python -m repro.analysis.lint --strict
 
 echo "== tier-1: pytest =="
-# Two failures predate the seed (multi-device dryrun subprocess and the HLO
-# analyzer depend on a newer jax than the container ships); deselect them so
-# -x gates on everything else.
-python -m pytest -x -q \
-    --deselect tests/test_distribution.py::test_tiny_mesh_dryrun_subprocess \
-    --deselect tests/test_hlo_cost.py::test_analyzer_on_known_program
+python -m pytest -x -q
 
 echo "== smoke: offload runtime (tiny config, 2 decode steps) =="
 python - <<'EOF'
