@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.core import alpha as alpha_lib
 from repro.core.param_manager import (AsyncParamManager, Entry,
-                                      plan_prefetch_order)
+                                      entry_parts, plan_prefetch_order)
 from repro.kernels import ops as kernel_ops
 from repro.kernels.q8_matmul import quantize_weights_np
 from repro.telemetry.tracer import NULL_TRACER, Tracer
@@ -87,8 +87,26 @@ class StreamStats:
                            wall=max(self.wall, other.wall))
 
 
+def engine_matmul(x, w):
+    """The device share of a linear (jitted; named so that its ops name
+    it in a device profile)."""
+    return x @ w
+
+
+def engine_q8_matmul(x, q, s):
+    """The device share of a linear streamed as int8 + per-column scales
+    (jitted); prefill activations are (B, S, K), the kernel wants 2D."""
+    y = kernel_ops.q8_matmul(x.reshape((-1, x.shape[-1])), q, s)
+    return y.reshape(x.shape[:-1] + (q.shape[-1],))
+
+
 class HeteGenEngine:
-    """Executes named linears under a placement plan with async overlap."""
+    """Executes named linears under a placement plan with async overlap.
+
+    Every blocking call :meth:`linear` makes on the serving thread sits in
+    a span on the ``wait`` track, named for what it waits on
+    (``act_to_host``, ``pin``, ``transfer``, ``device_sync``,
+    ``host_gemm``) and carrying ``module`` and ``phase``."""
 
     def __init__(self, weights: Dict[str, np.ndarray],
                  plan: Sequence[ModulePlan], *,
@@ -153,6 +171,13 @@ class HeteGenEngine:
                 groups[p.name] = p.group
             if cols < w.shape[-1]:
                 self._host_part[p.name] = np.ascontiguousarray(w[..., cols:])
+        # host memory the partition holds apart from the caller's weights
+        # (a whole-width shard or a host-only module is a view, no copy)
+        held = [(n, a) for n, src in stage_src.items()
+                for a in entry_parts(src)] + list(self._host_part.items())
+        self.host_bytes_copied = sum(
+            0 if np.may_share_memory(a, weights[n]) else a.nbytes
+            for n, a in held)
 
         self.manager = (AsyncParamManager(stage_src, groups,
                                           tracer=tracer,
@@ -167,14 +192,8 @@ class HeteGenEngine:
         self._trans_pool = ThreadPoolExecutor(max_workers=1,
                                               thread_name_prefix="transfer")
 
-        self._matmul = jax.jit(lambda x, w: x @ w)
-
-        def _q8_matmul(x, q, s):
-            # prefill activations are (B, S, K); the kernel wants 2D
-            y = kernel_ops.q8_matmul(x.reshape((-1, x.shape[-1])), q, s)
-            return y.reshape(x.shape[:-1] + (q.shape[-1],))
-
-        self._q8_matmul = jax.jit(_q8_matmul)
+        self._matmul = jax.jit(engine_matmul)
+        self._q8_matmul = jax.jit(engine_q8_matmul)
         self._t_start = time.perf_counter()
 
     # ------------------------------------------------------------------
@@ -225,6 +244,11 @@ class HeteGenEngine:
         return arrs if isinstance(buf, tuple) else arrs[0]
 
     # ------------------------------------------------------------------
+    def _wait(self, kind: str, name: str):
+        """A span on the ``wait`` track around one blocking call."""
+        return self.tracer.span(kind, track="wait", module=name,
+                                phase=self.trace_phase)
+
     def linear(self, x: jax.Array, name: str) -> jax.Array:
         """y = x @ W[name] (+ bias), executed per the placement plan."""
         p = self.plan[name]
@@ -233,9 +257,10 @@ class HeteGenEngine:
                                   phase=self.trace_phase):
                 t0 = time.perf_counter()
                 y = self._matmul(x, self._resident[name])
-                # lint: allow[hot-path-sync] device-stream timing: dev
-                # busy-seconds are the alpha controller's input signal
-                y.block_until_ready()
+                with self._wait("device_sync", name):
+                    # lint: allow[hot-path-sync] device-stream timing: dev
+                    # busy-seconds are the alpha controller's input signal
+                    y.block_until_ready()
                 with self._lock:
                     self.stats.dev += time.perf_counter() - t0
         else:
@@ -252,10 +277,11 @@ class HeteGenEngine:
             #    as in the paper: "transmitting activation from the GPU")
             host_fut = None
             if has_host:
-                # lint: allow[hot-path-sync] the paper's §4.2 activation
-                # move: the host GEMM share needs x on the CPU, and this
-                # transfer is what the alpha split already budgets for
-                x_np = np.asarray(x)
+                with self._wait("act_to_host", name):
+                    # lint: allow[hot-path-sync] the paper's §4.2 activation
+                    # move: the host GEMM share needs x on the CPU, and this
+                    # transfer is what the alpha split already budgets for
+                    x_np = np.asarray(x)
                 host_fut = self._cpu_pool.submit(self._host_matmul, x_np, name)
 
             # 3. device share: acquire pinned buffer, DMA, matmul.  The slot
@@ -269,29 +295,33 @@ class HeteGenEngine:
                 seq = self.manager.seq_of(name)
                 w_fut = self._trans_pool.submit(self._transfer, buf, name,
                                                 seq)
-                w_dev = w_fut.result()
+                with self._wait("transfer", name):
+                    w_dev = w_fut.result()
                 with self.tracer.span(name, track="device", module=name,
                                       phase=self.trace_phase, seq=seq):
                     t0 = time.perf_counter()
                     y_dev = (self._q8_matmul(x, *w_dev)
                              if isinstance(w_dev, tuple)
                              else self._matmul(x, w_dev))
-                    # lint: allow[hot-path-sync] ring-slot release ordering:
-                    # jax's CPU backend zero-copies device_put, so the read
-                    # must finish before the slot is re-staged (see above)
-                    y_dev.block_until_ready()
+                    with self._wait("device_sync", name):
+                        # lint: allow[hot-path-sync] ring-slot release
+                        # ordering: jax's CPU backend zero-copies
+                        # device_put, so the read must finish before the
+                        # slot is re-staged (see above)
+                        y_dev.block_until_ready()
                     with self._lock:
                         self.stats.dev += time.perf_counter() - t0
                 self.manager.release(name)
 
             # 4. combine
-            if y_dev is None:
-                y = jnp.asarray(host_fut.result())
-            elif host_fut is None:
+            if host_fut is None:
                 y = y_dev
             else:
-                y_host = jnp.asarray(host_fut.result())
-                y = jnp.concatenate([y_dev, y_host], axis=-1)
+                with self._wait("host_gemm", name):
+                    y_np = host_fut.result()
+                y_host = jnp.asarray(y_np)
+                y = y_host if y_dev is None else \
+                    jnp.concatenate([y_dev, y_host], axis=-1)
 
         if name in self.biases:
             y = y + self.biases[name]

@@ -134,18 +134,12 @@ class AsyncParamManager:
         self._seq: Dict[str, int] = {}    # per-module pin counter
         self._pinner = ThreadPoolExecutor(max_workers=1,
                                           thread_name_prefix="pin")
-        self.events: List[tuple] = []     # (op, module, t) for tests/metrics
-        self._events_lock = threading.Lock()
         # accumulated by the pin thread, read/reset by the engine thread —
         # guarded the same way HeteGenEngine.stats is
         self._pin_lock = threading.Lock()
         self._pin_seconds = 0.0
 
     # ------------------------------------------------------------------
-    def _log(self, op: str, name: str) -> None:
-        with self._events_lock:
-            self.events.append((op, name, time.perf_counter()))
-
     def _do_pin(self, slot: PinSlot, name: str, seq: int) -> Entry:
         src = self.weights[name]
         parts = entry_parts(src)
@@ -168,7 +162,6 @@ class AsyncParamManager:
             dt = time.perf_counter() - t0
             with self._pin_lock:
                 self._pin_seconds += dt
-        self._log("pinned", name)
         return tuple(views) if isinstance(src, (tuple, list)) else views[0]
 
     def _submit_pin(self, slot: PinSlot, name: str) -> None:
@@ -214,7 +207,8 @@ class AsyncParamManager:
             if slot is None:
                 return False          # ring full: caller retries after release
             self._submit_pin(slot, name)
-            self._log("pin_start", name)
+            self.tracer.event("pin_start", track="pin", module=name,
+                              phase=self.trace_phase)
             return True
 
     def acquire(self, name: str) -> Entry:
@@ -226,35 +220,48 @@ class AsyncParamManager:
         evicted — ``acquire`` always makes progress unless both slots are
         simultaneously *in use*, which the engine's prompt ``release`` rules
         out.
+
+        The whole call is one ``pin`` span on the ``wait`` track (the
+        caller's wait for its staged weights), with ``miss=True`` when
+        the prefetch never happened and the pin ran synchronously.
         """
         ring = self.rings[self.groups[name]]
-        with ring.lock:
-            slot = ring.slot_for(name)
-            if slot is None:
-                slot = ring.free_slot()
+        with self.tracer.span("pin", track="wait", module=name,
+                              phase=self.trace_phase) as sp:
+            with ring.lock:
+                slot = ring.slot_for(name)
                 if slot is None:
-                    # evict a staged, not-in-use slot
-                    deadline = time.monotonic() + 30.0
-                    while slot is None:
-                        for s in ring.slots:
-                            if not s.in_use and s.name != name:
-                                slot = s
-                                break
-                        if slot is None:
-                            if not ring.lock.wait(timeout=0.5) and \
-                                    time.monotonic() > deadline:
-                                raise RuntimeError(
-                                    f"pin ring wedged acquiring {name!r}: "
-                                    f"both slots in use")
-                    if slot.ready is not None:
-                        slot.ready.result()   # drain in-flight pin first
-                        self._log("evicted", slot.name or "?")
-                self._submit_pin(slot, name)
-                self._log("pin_start_sync", name)
-            slot.in_use = True
-        arr = slot.ready.result()
-        self._log("acquired", name)
-        return arr
+                    sp.set(miss=True)
+                    slot = self._claim_slot(ring, name)
+                    self._submit_pin(slot, name)
+                    self.tracer.event("pin_sync", track="pin", module=name,
+                                      phase=self.trace_phase)
+                slot.in_use = True
+            return slot.ready.result()
+
+    def _claim_slot(self, ring: GroupRing, name: str) -> PinSlot:
+        """A slot to pin ``name`` into now: a free one, else a staged,
+        not-in-use one, evicted.  Caller must hold the ring lock."""
+        slot = ring.free_slot()
+        if slot is not None:
+            return slot
+        deadline = time.monotonic() + 30.0
+        while slot is None:
+            for s in ring.slots:
+                if not s.in_use and s.name != name:
+                    slot = s
+                    break
+            if slot is None:
+                if not ring.lock.wait(timeout=0.5) and \
+                        time.monotonic() > deadline:
+                    raise RuntimeError(
+                        f"pin ring wedged acquiring {name!r}: "
+                        f"both slots in use")
+        if slot.ready is not None:
+            slot.ready.result()   # drain in-flight pin first
+            self.tracer.event("evict", track="pin", module=slot.name,
+                              phase=self.trace_phase)
+        return slot
 
     def release(self, name: str) -> None:
         """Mark ``name``'s slot reusable (its transfer has consumed it)."""
@@ -266,7 +273,6 @@ class AsyncParamManager:
                 slot.ready = None
                 slot.in_use = False
                 ring.lock.notify_all()
-        self._log("released", name)
 
     # ------------------------------------------------------------------
     def pinned_overhead_bytes(self) -> int:

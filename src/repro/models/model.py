@@ -1231,10 +1231,13 @@ def make_backend_ops(cfg: ModelConfig) -> Dict:
                              q_positions, kv_len, window,
                              k_scale=k_scale, v_scale=v_scale)
 
+    def _logits(shared, x):
+        return lm_logits(cfg, shared, x)
+
     return {"norm": jax.jit(partial(L.apply_norm, cfg)),
             "attend": jax.jit(_attend, static_argnums=(5,)),
             "paged_attend": jax.jit(_paged, static_argnums=(6,)),
-            "logits": jax.jit(lambda shared, x: lm_logits(cfg, shared, x))}
+            "logits": jax.jit(_logits)}
 
 
 def extract_backend_params(cfg: ModelConfig, params: Dict):

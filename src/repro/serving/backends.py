@@ -374,30 +374,41 @@ class HeteGenBackend:
                            self.alpha_override if p.mode == "hetegen"
                            else p.alpha)
                 for p in pol.plan]
-        old = self.engines.pop(phase, None)
-        if old is not None:
-            # a replaced partition's busy seconds still happened: bank
-            # them so finish_stats never undercounts across retunes
-            self._stats_tally = self._stats_tally + old.finish_stats()
-            old.close()
         self.policies[phase] = pol
-        # drop store entries no current plan keeps resident BEFORE building
-        # the new engine, so stale device copies are released
-        keep = {p.name for r in self.policies.values()
-                for p in r.plan if p.mode == "resident"}
-        for name in list(self._resident_store):
-            if name not in keep:
-                del self._resident_store[name]
-        eng = HeteGenEngine(self._host_weights, pol.plan,
-                            biases=self._host_biases,
-                            resident_store=self._resident_store,
-                            tracer=self.tracer, trace_phase=phase,
-                            wstream=self.wstream)
-        eng.warm_prefetch()
-        self.engines[phase] = eng
+        self._build_engine(phase)
         if phase == "decode":
             self.batch = batch
         return pol
+
+    def _build_engine(self, phase: str) -> None:
+        """Replace ``phase``'s engine with one partitioned for its current
+        plan, inside one ``build`` span on the ``backend`` track."""
+        pol = self.policies[phase]
+        with self.tracer.span("build", track="backend", phase=phase,
+                              batch=pol.batch,
+                              tokens_per_seq=pol.tokens_per_seq,
+                              alpha=float(pol.alpha)) as sp:
+            old = self.engines.pop(phase, None)
+            if old is not None:
+                # a replaced partition's busy seconds still happened: bank
+                # them so finish_stats never undercounts across rebuilds
+                self._stats_tally = self._stats_tally + old.finish_stats()
+                old.close()
+            # drop store entries no current plan keeps resident BEFORE
+            # building the new engine, so stale device copies are released
+            keep = {p.name for r in self.policies.values()
+                    for p in r.plan if p.mode == "resident"}
+            for name in list(self._resident_store):
+                if name not in keep:
+                    del self._resident_store[name]
+            eng = HeteGenEngine(self._host_weights, pol.plan,
+                                biases=self._host_biases,
+                                resident_store=self._resident_store,
+                                tracer=self.tracer, trace_phase=phase,
+                                wstream=self.wstream)
+            eng.warm_prefetch()
+            sp.set(host_bytes=eng.host_bytes_copied)
+            self.engines[phase] = eng
 
     def _ensure_prefill_plan(self, batch: int, seq: int) -> None:
         """Tune the prefill plan to the observed prompt shape, with
@@ -463,17 +474,7 @@ class HeteGenBackend:
                                alpha if p.mode == "hetegen" else p.alpha)
                     for p in pol.plan]
         pol.alpha = float(alpha)
-        old = self.engines.pop(phase, None)
-        if old is not None:
-            self._stats_tally = self._stats_tally + old.finish_stats()
-            old.close()
-        eng = HeteGenEngine(self._host_weights, pol.plan,
-                            biases=self._host_biases,
-                            resident_store=self._resident_store,
-                            tracer=self.tracer, trace_phase=phase,
-                            wstream=self.wstream)
-        eng.warm_prefetch()
-        self.engines[phase] = eng
+        self._build_engine(phase)
 
     def _maybe_recalibrate(self) -> None:
         """Periodic trace-driven re-tune, called at the top of a decode
@@ -522,24 +523,29 @@ class HeteGenBackend:
                             n_pages=n_pages, kv_dtype=kv_dtype, check=check)
 
     def prefill(self, batch: Dict, cache: Dict) -> Tuple[Dict, jax.Array]:
+        if "tokens" in batch:
+            b, s = batch["tokens"].shape
+        else:
+            b, s = batch["embeds"].shape[:2]
         if self.phase_plans:
-            if "tokens" in batch:
-                b, s = batch["tokens"].shape
-            else:
-                b, s = batch["embeds"].shape[:2]
             self._ensure_prefill_plan(b, s)
             self._phase = "prefill"
         try:
-            return M.backend_prefill(self.cfg, self.shared, batch, cache,
-                                     linear=self.linear, ops=self._ops)
+            with self.tracer.span("prefill", track="backend", b=int(b),
+                                  s=int(s)):
+                return M.backend_prefill(self.cfg, self.shared, batch,
+                                         cache, linear=self.linear,
+                                         ops=self._ops)
         finally:
             self._phase = "decode"
 
     def decode(self, token: jax.Array, cache: Dict
                ) -> Tuple[Dict, jax.Array]:
         self._maybe_recalibrate()
-        return M.backend_decode(self.cfg, self.shared, token, cache,
-                                linear=self.linear, ops=self._ops)
+        with self.tracer.span("decode", track="backend",
+                              rows=int(token.shape[0])):
+            return M.backend_decode(self.cfg, self.shared, token, cache,
+                                    linear=self.linear, ops=self._ops)
 
     def verify(self, batch: Dict, cache: Dict) -> Tuple[Dict, jax.Array]:
         """Speculative scoring pass under the "verify" phase plan —
@@ -547,14 +553,16 @@ class HeteGenBackend:
         pushes toward the accelerator even though the step advances the
         decode frontier."""
         self._maybe_recalibrate()
+        b, s = batch["tokens"].shape
         if self.phase_plans:
-            b, s = batch["tokens"].shape
             self._ensure_verify_plan(b, s)
             self._phase = "verify"
         try:
-            return M.backend_prefill(self.cfg, self.shared, batch, cache,
-                                     linear=self.linear, ops=self._ops,
-                                     all_logits=True)
+            with self.tracer.span("verify", track="backend", b=int(b),
+                                  s=int(s)):
+                return M.backend_prefill(self.cfg, self.shared, batch,
+                                         cache, linear=self.linear,
+                                         ops=self._ops, all_logits=True)
         finally:
             self._phase = "decode"
 

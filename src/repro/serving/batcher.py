@@ -330,7 +330,8 @@ class ContinuousBatcher:
         self.cache["len"] = self.cache["len"].at[slot].set(
             toks.shape[1])
         self.tokens = self.tokens.at[slot].set(first[0])
-        st.generated.append(int(first[0]))
+        with self._readback("prefill", 1):
+            st.generated.append(int(first[0]))
         self._maybe_finish(st)
 
     def _prefill_dense_slot(self, slot: int, toks: jax.Array) -> jax.Array:
@@ -408,11 +409,12 @@ class ContinuousBatcher:
                         glob, row.astype(glob.dtype), slot, axis=axis)
                 self.cache[key] = glob
         firsts = self._sample_slot_rows(logits, slots)
-        for i, st in enumerate(sts):
-            self.cache["len"] = self.cache["len"].at[st.slot].set(n)
-            self.tokens = self.tokens.at[st.slot].set(firsts[i])
-            st.generated.append(int(firsts[i]))
-            self._maybe_finish(st)
+        with self._readback("prefill", len(sts)):
+            for i, st in enumerate(sts):
+                self.cache["len"] = self.cache["len"].at[st.slot].set(n)
+                self.tokens = self.tokens.at[st.slot].set(firsts[i])
+                st.generated.append(int(firsts[i]))
+                self._maybe_finish(st)
 
     def _prefill_chunk(self, st: RequestState) -> None:
         """Advance one chunk of a chunked prefill: run tokens
@@ -462,8 +464,15 @@ class ContinuousBatcher:
         first = self._sample_slot_rows(logits, [slot])
         self.cache["len"] = self.cache["len"].at[slot].set(n)
         self.tokens = self.tokens.at[slot].set(first[0])
-        st.generated.append(int(first[0]))
+        with self._readback("prefill", 1):
+            st.generated.append(int(first[0]))
         self._maybe_finish(st)
+
+    def _readback(self, phase: str, rows: int):
+        """A ``token_readback`` span on the ``wait`` track around reading
+        ``rows`` sampled tokens back to the host, one sync each."""
+        return self.tracer.span("token_readback", track="wait",
+                                module="batcher", phase=phase, syncs=rows)
 
     def _maybe_finish(self, st: RequestState) -> None:
         hit_eos = (st.eos is not None and st.generated
@@ -593,7 +602,10 @@ class ContinuousBatcher:
                 self._spec_step(proposals, active)
             return int(self.scheduler.active_mask().sum())
         sp.set(phase="decode")
-        with self.tracer.span("decode", track="phase"):
+        running = self.scheduler.running()
+        with self.tracer.span("decode", track="phase", rows=executed,
+                              kv_tokens=sum(st.kv_len + 1
+                                            for st in running)):
             if self.paged and occ < self.max_slots:
                 self._decode_active_slots(active)
             else:
@@ -603,9 +615,10 @@ class ContinuousBatcher:
                 self.tokens = self._sample_slot_rows(
                     logits, list(range(self.max_slots)))
         nxt = self.tokens
-        for st in self.scheduler.running():
-            st.generated.append(int(nxt[st.slot]))
-            self._maybe_finish(st)
+        with self._readback("decode", len(running)):
+            for st in running:
+                st.generated.append(int(nxt[st.slot]))
+                self._maybe_finish(st)
         return int(self.scheduler.active_mask().sum())
 
     def _draft_proposals(self) -> Dict[int, List[int]]:

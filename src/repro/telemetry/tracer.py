@@ -25,6 +25,12 @@ Design constraints, in order:
   immediately — no allocation, no timestamp, no branch beyond one
   attribute check.  Serving code therefore instruments unconditionally
   and leaves the tracer off in production-critical paths.
+* **One clock with the device.**  An enabled tracer's span also enters a
+  ``jax.profiler.TraceAnnotation`` named ``<track>:<name>`` carrying the
+  span's scalar attributes, on the thread that runs it.  Under
+  ``jax.profiler`` every program span then sits beside the device ops
+  on the profiler's own clock; with no profile running the annotation
+  records nothing.
 
 Tracks are logical streams, not threads: a span lands on its explicit
 ``track=`` when given, else on the calling thread's default track
@@ -47,6 +53,10 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
+
+_SCALARS = (bool, int, float, str)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,9 +104,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _LiveSpan:
-    """Context manager that records one span on exit."""
+    """Context manager that records one span on exit, mirrored into a
+    profiler annotation while it is open."""
 
-    __slots__ = ("_buf", "name", "track", "attrs", "t0")
+    __slots__ = ("_buf", "name", "track", "attrs", "t0", "_ann")
 
     def __init__(self, buf: "_ThreadBuf", name: str, track: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -106,12 +117,18 @@ class _LiveSpan:
         self.attrs = attrs
 
     def __enter__(self) -> "_LiveSpan":
+        attrs = self.attrs or {}
+        self._ann = TraceAnnotation(
+            f"{self.track}:{self.name}",
+            **{k: v for k, v in attrs.items() if isinstance(v, _SCALARS)})
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._buf.add_span(self.name, self.track, self.t0,
-                           time.perf_counter(), self.attrs)
+        t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        self._buf.add_span(self.name, self.track, self.t0, t1, self.attrs)
         return False
 
     def set(self, **attrs) -> None:

@@ -3,6 +3,7 @@ import numpy as np
 import pytest
 
 from repro.core.param_manager import AsyncParamManager, plan_prefetch_order
+from repro.telemetry.tracer import Tracer
 
 
 def _mk(names, shape=(64, 64)):
@@ -23,7 +24,8 @@ def test_acquire_returns_exact_weights():
 def test_prefetch_overlap_order():
     w = _mk([f"m{i}" for i in range(6)])
     groups = {n: "g" for n in w}
-    mgr = AsyncParamManager(w, groups)
+    tr = Tracer()
+    mgr = AsyncParamManager(w, groups, tracer=tr)
     order = list(w)
     nxt = plan_prefetch_order(order, groups)
     mgr.prefetch(order[0])
@@ -33,10 +35,17 @@ def test_prefetch_overlap_order():
         got = mgr.acquire(n)
         np.testing.assert_array_equal(got, w[n])
         mgr.release(n)
-    ops = [e[0] for e in mgr.events]
-    # at least one pin started before the previous acquire completed
-    assert "pin_start" in ops
     mgr.shutdown()
+    evs = tr.events_list(track="pin")
+    # every module's pin started ahead of its acquire (plus the wrap
+    # prefetch of the next step's first module); no acquire missed
+    starts = [e.attrs["module"] for e in evs if e.name == "pin_start"]
+    assert starts == order + [order[0]]
+    assert not [e for e in evs if e.name != "pin_start"]
+    waits = tr.spans(track="wait")
+    assert [s.attrs["module"] for s in waits] == order
+    assert not any(s.attrs.get("miss") for s in waits)
+    assert not hasattr(mgr, "events")
 
 
 def test_ring_bound_two_slots_per_group():
@@ -62,12 +71,19 @@ def test_groups_isolated():
 def test_eviction_unclogs_ring():
     """Prefetched-but-unconsumed entries must not deadlock acquire."""
     w = _mk(["a", "b", "c"])
-    mgr = AsyncParamManager(w, {n: "g" for n in w})
+    tr = Tracer()
+    mgr = AsyncParamManager(w, {n: "g" for n in w}, tracer=tr)
     mgr.prefetch("a"); mgr.prefetch("b")     # ring full with a, b
     got = mgr.acquire("c")                   # must evict, not hang
     np.testing.assert_array_equal(got, w["c"])
     mgr.release("c")
     mgr.shutdown()
+    # the miss is visible: an eviction, a synchronous pin, a missed wait
+    evs = [(e.name, e.attrs["module"]) for e in tr.events_list(track="pin")]
+    assert evs[:2] == [("pin_start", "a"), ("pin_start", "b")]
+    assert evs[2][0] == "evict" and evs[3] == ("pin_sync", "c")
+    (wait,) = tr.spans(track="wait")
+    assert wait.attrs == {"module": "c", "phase": None, "miss": True}
 
 
 def test_wrap_around_prefetch_order():

@@ -534,3 +534,207 @@ def test_llm_facade_trace_and_metrics(rng):
         float(st["scheduler"]["preemptions"])
     assert snap["serve.tokens"] == 15.0
     assert snap["tokens_per_s"] == pytest.approx(st["tokens_per_s"])
+
+
+# ---------------------------------------------------------------------------
+# wait and backend spans; profiler annotations
+# ---------------------------------------------------------------------------
+
+WAIT_KINDS = ("act_to_host", "pin", "transfer", "device_sync", "host_gemm")
+
+
+@pytest.fixture(scope="module")
+def split_setup():
+    """An OPT block wide enough that alpha 0.5 splits every linear into
+    one 128-column device tile and a host share."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_config, reduced
+    from repro.models import model as M
+
+    cfg = dataclasses.replace(
+        reduced(get_config("opt-125m"), layers=2), name="opt-split",
+        d_model=256, n_heads=4, head_dim=64, d_ff=512)
+    return cfg, M.init_params(cfg, jax.random.PRNGKey(1))
+
+
+def _split_backend(split_setup, tracer, batch=2):
+    from repro.core.hw import PAPER_A10
+    from repro.serving.backends import HeteGenBackend
+
+    cfg, params = split_setup
+    return HeteGenBackend(cfg, params, hw=PAPER_A10, budget_bytes=0,
+                          batch=batch, alpha_override=0.5, tracer=tracer)
+
+
+def _serve(split_setup, tracer, prompts, max_new=4):
+    from repro.serving.batcher import ContinuousBatcher
+
+    cfg, _ = split_setup
+    be = _split_backend(split_setup, tracer)
+    with ContinuousBatcher(cfg, backend=be, own_backend=True, max_slots=2,
+                           max_len=64, paged=True, tracer=tracer) as b:
+        ids = [b.submit(p, max_new) for p in prompts]
+        out = b.run_until_done()
+        order = list(be.engine.order)
+    return [out[i] for i in ids], order
+
+
+def _prompts(split_setup, rng):
+    cfg, _ = split_setup
+    return [list(rng.integers(0, cfg.vocab_size, n)) for n in (5, 9)]
+
+
+def test_split_linear_waits_once_each_per_decode_step(split_setup, rng):
+    """Every split linear of a traced decode step waits exactly once on
+    each stream, in spans carrying module and phase; the step's token
+    readback is one span counting a sync per row; the decode phase span
+    carries the step's rows and KV tokens from host-side state."""
+    tr = Tracer()
+    _, order = _serve(split_setup, tr, _prompts(split_setup, rng))
+    phases = tr.spans(track="phase")
+    decodes = [s for s in phases if s.name == "decode"]
+    # prompts of 5 and 9 tokens, one token from each prefill, then each
+    # decode step attends to kv_len + 1 = prompt + generated positions
+    assert [(s.attrs["rows"], s.attrs["kv_tokens"]) for s in decodes] == \
+        [(2, 16), (2, 18), (2, 20)]
+    waits = tr.spans(track="wait")
+    for d in decodes:
+        inside = [s for s in waits if d.t0 <= s.t0 and s.t1 <= d.t1]
+        assert all(s.attrs["phase"] == "decode" for s in inside)
+        counts = {}
+        for s in inside:
+            key = (s.attrs["module"], s.name)
+            counts[key] = counts.get(key, 0) + 1
+        assert counts == {(m, k): 1 for m in order for k in WAIT_KINDS}
+        back = [s for s in waits if s.name == "token_readback"
+                and d.t1 <= s.t0 and s.attrs["phase"] == "decode"]
+        assert back and back[0].attrs == {"module": "batcher",
+                                          "phase": "decode", "syncs": 2}
+    assert not any(s.attrs.get("miss") for s in waits if s.name == "pin"
+                   and s.attrs["phase"] == "decode" and s.t0 > decodes[1].t0)
+    assert validate_chrome_trace(to_chrome_trace(tr.spans())) == []
+
+
+def test_engine_builds_are_spanned(split_setup):
+    """retune and a recalibration rebuild each record one build span on
+    the backend track, with the plan's shape and the host bytes copied."""
+    tr = Tracer()
+    be = _split_backend(split_setup, tr)
+    linear_bytes = sum(w.nbytes for w in be._host_weights.values())
+    m = tr.mark()
+    be.retune(3)
+    (b1,) = tr.spans(since=m, track="backend")
+    assert b1.name == "build"
+    assert b1.attrs == {"phase": "decode", "batch": 3, "tokens_per_seq": 1,
+                        "alpha": be.policies["decode"].alpha,
+                        "host_bytes": linear_bytes}
+    m = tr.mark()
+    be._apply_alpha("decode", 0.5)
+    (b2,) = tr.spans(since=m, track="backend")
+    assert b2.attrs == dict(b1.attrs, alpha=0.5)
+    be.close()
+
+
+def test_traced_offload_batcher_token_identical(split_setup, rng):
+    """Wait, backend and phase-shape spans observe only: an offloaded
+    batcher serves the same tokens with and without the tracer."""
+    prompts = _prompts(split_setup, rng)
+    ref, _ = _serve(split_setup, NULL_TRACER, prompts)
+    tr = Tracer()
+    got, _ = _serve(split_setup, tr, prompts)
+    assert got == ref
+    names = {s.name for s in tr.spans(track="backend")}
+    assert {"build", "prefill", "decode"} <= names
+
+
+def test_null_tracer_constructs_no_annotation(monkeypatch, rng):
+    """The disabled path never builds a profiler annotation; the enabled
+    one builds one per span."""
+    import jax.numpy as jnp
+
+    import repro.telemetry.tracer as tracer_mod
+    from repro.core import HeteGenEngine, ModulePlan
+
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Counting)
+    W = {n: rng.standard_normal((96, 256)).astype(np.float32)
+         for n in ("a", "b")}
+    plan = [ModulePlan(n, "g", "hetegen", 0.5) for n in W]
+    x = jnp.ones((2, 96), jnp.float32)
+    eng = HeteGenEngine(W, plan, tracer=NULL_TRACER, trace_phase="decode")
+    for n in W:
+        eng.linear(x, n)
+    eng.close()
+    assert made == []
+    tr = Tracer()
+    eng = HeteGenEngine(W, plan, tracer=tr, trace_phase="decode")
+    for n in W:
+        eng.linear(x, n)
+    eng.close()
+    assert sorted(made) == sorted(f"{s.track}:{s.name}"
+                                  for s in tr.spans())
+
+
+def test_spans_land_in_the_profile_on_its_clock(tmp_path, rng):
+    """Under jax.profiler every program span appears as a host
+    annotation ``<track>:<name>`` with its scalar attributes, on the
+    thread that ran it, starting where the span started (within 1 ms,
+    through one clock-sync annotation)."""
+    import glob
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    from repro.core import HeteGenEngine, ModulePlan
+
+    W = {n: rng.standard_normal((96, 256)).astype(np.float32)
+         for n in ("a", "b", "c")}
+    plan = [ModulePlan(n, "g", "hetegen", 0.5) for n in W]
+    x = jnp.ones((2, 96), jnp.float32)
+    tr = Tracer()
+    eng = HeteGenEngine(W, plan, tracer=tr, trace_phase="decode")
+    eng.linear(x, "a")                    # compile outside the profile
+    tr.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("test.clock_sync"):
+            t_sync = time.perf_counter()
+        for n in W:
+            eng.linear(x, n)
+    finally:
+        jax.profiler.stop_trace()
+    eng.close()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = [(ev.name, ev.start_ns * 1e-9, dict(ev.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    (sync,) = [t for name, t, _ in events if name == "test.clock_sync"]
+    offset = sync - t_sync
+    spans = tr.spans()
+    assert {s.track for s in spans} >= {"wait", "pin", "transfer",
+                                        "cpu_gemm", "device"}
+    for s in spans:
+        name = f"{s.track}:{s.name}"
+        cands = [(abs(t - (s.t0 + offset)), st) for n, t, st in events
+                 if n == name]
+        assert cands, name
+        err, stats = min(cands, key=lambda c: c[0])
+        assert err < 1e-3, (name, err)
+        assert stats.get("module") == s.attrs["module"]
