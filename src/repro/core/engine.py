@@ -25,6 +25,17 @@ Executes the linear modules of a model under a per-module placement plan:
                 carry the wire bytes (plus ``fp_bytes``, the uncompressed
                 equivalent) so telemetry stays honest under compression.
 
+Every fp share (resident, streamed, host) is held ``(out, in)``, its
+columns as rows.  ``_host_matmul`` flattens the activation to one
+``(M, K)`` matrix and runs one BLAS GEMM against its share, so a decode
+step reads the share from memory once, not once per row of the batch
+(a 3-D ``x @ w`` is a stack of one-row products).  The weights come in
+as ``(in, out)`` arrays; where one is the view of an ``(out, in)``
+array, as ``HeteGenBackend`` holds them, each share is a row slice of
+it and the partition copies nothing.  Otherwise a share is copied into
+that layout, except a whole weight left to the host, which stays a
+view.  A q8 shard keeps the ``(in, out)`` layout its kernel reads.
+
 Four real executors provide the four streams of the paper's Fig. 5c: the
 host GEMM pool, the manager's pin thread, the transfer thread, and the
 device queue (JAX async dispatch).  On a TPU the device is the chip and
@@ -88,9 +99,9 @@ class StreamStats:
 
 
 def engine_matmul(x, w):
-    """The device share of a linear (jitted; named so that its ops name
-    it in a device profile)."""
-    return x @ w
+    """The device share of a linear, its weight held ``(out, in)``
+    (jitted; named so that its ops name it in a device profile)."""
+    return x @ w.T
 
 
 def engine_q8_matmul(x, q, s):
@@ -98,6 +109,19 @@ def engine_q8_matmul(x, q, s):
     (jitted); prefill activations are (B, S, K), the kernel wants 2D."""
     y = kernel_ops.q8_matmul(x.reshape((-1, x.shape[-1])), q, s)
     return y.reshape(x.shape[:-1] + (q.shape[-1],))
+
+
+def _row_major(a: np.ndarray) -> np.ndarray:
+    """``a`` (2-D) as a C-contiguous array: itself when it is one, else a
+    copy made 64 columns at a time.  When the copy transposes (a slice
+    of a weight held the other way round), the blocks run several times
+    faster than one ``np.ascontiguousarray``."""
+    if a.flags.c_contiguous:
+        return a
+    out = np.empty(a.shape, a.dtype)
+    for j in range(0, a.shape[1], 64):
+        out[:, j:j + 64] = a[:, j:j + 64]
+    return out
 
 
 class HeteGenEngine:
@@ -136,7 +160,7 @@ class HeteGenEngine:
         # serving phase) without holding duplicate device copies of the
         # modules both plans promote to residency.
         self._resident: Dict[str, jax.Array] = {}
-        self._host_part: Dict[str, np.ndarray] = {}
+        self._host_part: Dict[str, np.ndarray] = {}    # (out, in)
         self._dev_cols: Dict[str, int] = {}
         self._fp_shard_bytes: Dict[str, int] = {}   # uncompressed shard size
         stage_src: Dict[str, Entry] = {}
@@ -147,32 +171,32 @@ class HeteGenEngine:
                 if resident_store is not None and p.name in resident_store:
                     self._resident[p.name] = resident_store[p.name]
                 else:
-                    self._resident[p.name] = jax.device_put(w, self.device)
+                    self._resident[p.name] = jax.device_put(
+                        _row_major(w.T), self.device)
                     if resident_store is not None:
                         resident_store[p.name] = self._resident[p.name]
                 continue
-            if p.mode == "host":
-                self._host_part[p.name] = w
-                self._dev_cols[p.name] = 0
-                continue
-            a = 1.0 if p.mode == "stream" else p.alpha
+            a = {"stream": 1.0, "host": 0.0}.get(p.mode, p.alpha)
             cols = alpha_lib.split_columns(a, w.shape[-1], tile)
             self._dev_cols[p.name] = cols
             if cols > 0:
-                # contiguous copy so staging is a single memcpy
-                shard = np.ascontiguousarray(w[..., :cols])
-                self._fp_shard_bytes[p.name] = shard.nbytes
-                if wstream == "q8" and shard.ndim == 2:
+                dev = w[:, :cols]
+                self._fp_shard_bytes[p.name] = dev.nbytes
+                # contiguous, so staging is a single memcpy
+                if wstream == "q8":
                     # one-time load cost: the shard streams as int8
-                    # payload + fp32 per-column scales from here on
-                    stage_src[p.name] = quantize_weights_np(shard)
+                    # payload + fp32 per-column scales from here on, in
+                    # the (in, out) layout the q8 kernel reads
+                    q, scale = quantize_weights_np(dev)
+                    stage_src[p.name] = (_row_major(q), scale)
                 else:
-                    stage_src[p.name] = shard
+                    stage_src[p.name] = _row_major(dev.T)
                 groups[p.name] = p.group
             if cols < w.shape[-1]:
-                self._host_part[p.name] = np.ascontiguousarray(w[..., cols:])
+                host = w[:, cols:].T
+                self._host_part[p.name] = _row_major(host) if cols else host
         # host memory the partition holds apart from the caller's weights
-        # (a whole-width shard or a host-only module is a view, no copy)
+        # (a share already laid out (out, in) is a view, no copy)
         held = [(n, a) for n, src in stage_src.items()
                 for a in entry_parts(src)] + list(self._host_part.items())
         self.host_bytes_copied = sum(
@@ -215,7 +239,9 @@ class HeteGenEngine:
         with self.tracer.span(name, track="cpu_gemm", bytes=w.nbytes,
                               module=name, phase=self.trace_phase):
             t0 = time.perf_counter()
-            y = x_np @ w
+            x2 = x_np.reshape((-1, x_np.shape[-1]))
+            y = np.ascontiguousarray(
+                (w @ x2.T).T.reshape(x_np.shape[:-1] + (w.shape[0],)))
             with self._lock:
                 self.stats.cpu += time.perf_counter() - t0
         return y

@@ -305,7 +305,12 @@ class HeteGenBackend:
         self.cfg = cfg
         shared, weights, biases = M.extract_backend_params(cfg, params)
         self.shared = shared
-        self._host_weights = {k: _np(v) for k, v in weights.items()}
+        # each weight is held once, (out, in) (transposed on the device
+        # before it moves), and handed to the engines as its (in, out)
+        # view: every share a partition makes is then a row slice of it,
+        # with nothing copied per engine build (core/engine.py)
+        self._host_weights = {k: _np(jnp.swapaxes(v, -1, -2)).T
+                              for k, v in weights.items()}
         self._host_biases = {k: _np(v) for k, v in biases.items()}
         self._ops = M.make_backend_ops(cfg)   # jitted norms/attention/head
         self.wstream = wstream

@@ -620,17 +620,18 @@ def test_split_linear_waits_once_each_per_decode_step(split_setup, rng):
 
 def test_engine_builds_are_spanned(split_setup):
     """retune and a recalibration rebuild each record one build span on
-    the backend track, with the plan's shape and the host bytes copied."""
+    the backend track, with the plan's shape and the host bytes copied
+    (none: the backend holds its weights (out, in), so every share of an
+    fp split is a view of them)."""
     tr = Tracer()
     be = _split_backend(split_setup, tr)
-    linear_bytes = sum(w.nbytes for w in be._host_weights.values())
     m = tr.mark()
     be.retune(3)
     (b1,) = tr.spans(since=m, track="backend")
     assert b1.name == "build"
     assert b1.attrs == {"phase": "decode", "batch": 3, "tokens_per_seq": 1,
                         "alpha": be.policies["decode"].alpha,
-                        "host_bytes": linear_bytes}
+                        "host_bytes": 0}
     m = tr.mark()
     be._apply_alpha("decode", 0.5)
     (b2,) = tr.spans(since=m, track="backend")
