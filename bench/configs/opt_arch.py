@@ -1,10 +1,16 @@
-"""Seeded weights for an OPT-shaped configuration, made on the device.
+"""The OPT block (Zhang et al. 2022) as the benchmark runs it: seeded
+weights, the program's configuration and parameter tree, and the work
+each token costs.
 
-The benchmark makes its weights itself, in one jitted call from the run's
-seed, in the dtype they are served in.  The same call feeds the program
-(converted to the program's parameter layout by :func:`program_params`)
-and, after the program has been freed, the plain reference: the
-reference never reads anything the program made.
+A configuration file names this module with ``"arch": "opt"``; the
+harness finds it by that name (``bench/harness/spec.py``) and calls only
+the four functions below, so nothing in the harness knows the block.
+
+Weights are made on the device in one jitted call from the run's seed,
+in the dtype they are served in.  The same call feeds the program
+(converted to its parameter layout by :func:`program_params`) and, after
+the program has been freed, the plain reference: the reference never
+reads anything the program made.
 
 Layout (leading axis = layer for every ``layers`` leaf)::
 
@@ -21,6 +27,7 @@ identity so their code paths are exercised.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Dict
 
@@ -29,8 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 
 LINEARS = ("wq", "wk", "wv", "wo", "w_in", "w_down")
-BIASES = {"wq": "bq", "wk": "bk", "wv": "bv", "wo": "bo",
-          "w_in": "b_in", "w_down": "b_down"}
 
 
 def shapes(sizes: Dict) -> Dict:
@@ -59,9 +64,9 @@ def _std(name: str, shape, init: Dict) -> float:
     return init["bias_std"]
 
 
-def make_fn(sizes: Dict, init: Dict, dtype=jnp.float32):
-    """A jitted ``key -> weights`` for these sizes."""
-    shp = shapes(sizes)
+def make_weights(conf: Dict):
+    """A jitted ``key -> weights`` for this configuration."""
+    shp, init, dtype = shapes(conf), conf["init"], jnp.dtype(conf["dtype"])
     flat = [("embed", shp["embed"]), ("pos", shp["pos"]),
             ("final_ln_scale", shp["final_ln_scale"]),
             ("final_ln_bias", shp["final_ln_bias"])]
@@ -84,8 +89,29 @@ def make_fn(sizes: Dict, init: Dict, dtype=jnp.float32):
     return jax.jit(make)
 
 
-def seed_key(seed: int) -> jax.Array:
-    return jax.random.PRNGKey(int(seed))
+def program_config(conf: Dict):
+    """The program's ``ModelConfig`` for a configuration file, checked to
+    hold exactly the file's sizes and the OPT block."""
+    from repro.configs import get_config
+
+    base = get_config(conf["program_arch"])
+    s = conf
+    cfg = dataclasses.replace(
+        base, n_layers=s["num_hidden_layers"], d_model=s["hidden_size"],
+        n_heads=s["num_attention_heads"],
+        n_kv_heads=s["num_attention_heads"], d_ff=s["ffn_dim"],
+        vocab_size=s["vocab_size"], max_seq=s["max_position_embeddings"],
+        dtype=conf["dtype"])
+    want = dict(pos_emb="learned", norm_kind="layernorm", mlp_kind="relu",
+                attn_bias=True, tie_embeddings=True, family="dense",
+                attn_kind="gqa")
+    for k, v in want.items():
+        if getattr(cfg, k) != v:
+            raise ValueError(f"{conf['program_arch']}: {k}={getattr(cfg, k)!r}"
+                             f" is not the OPT block ({v!r})")
+    if cfg.hd * cfg.n_heads != cfg.d_model:
+        raise ValueError("head size times heads must equal the hidden size")
+    return cfg
 
 
 def program_params(w: Dict) -> Dict:
@@ -111,3 +137,27 @@ def program_params(w: Dict) -> Dict:
             "final_norm": {"scale": w["final_ln_scale"],
                            "bias": w["final_ln_bias"]},
             "blocks": blocks}
+
+
+def counts(conf: Dict) -> Dict[str, int]:
+    """The work of one token, from sizes alone (``bench/harness/flops.py``
+    reads these keys):
+
+    ``linear_params``       weights a token multiplies through all layers:
+                            q, k, v, o (4 d^2) and in, down (2 d f) each
+    ``head_params``         weights of the output head (tied: V d)
+    ``paged_layers``        layers whose decode runs the paged kernel
+    ``kv_bytes_per_key``    one key's K and V in one layer (40 heads of
+                            128 = d: multi-head attention)
+    ``qo_bytes_per_row``    one row's query and output in one layer
+    ``attn_flops_per_key``  scores and weighted sum, 2 FLOPs each per
+                            head dimension
+    """
+    L, d, f = conf["num_hidden_layers"], conf["hidden_size"], conf["ffn_dim"]
+    item = jnp.dtype(conf["dtype"]).itemsize
+    return {"linear_params": L * (4 * d * d + 2 * d * f),
+            "head_params": conf["vocab_size"] * d,
+            "paged_layers": L,
+            "kv_bytes_per_key": 2 * d * item,
+            "qo_bytes_per_row": 2 * d * item,
+            "attn_flops_per_key": 4 * d}

@@ -70,10 +70,17 @@ def _layer(x, p, n_heads: int, control: Optional[str]):
     return x + lin(h, p["w_down"], p["b_down"])
 
 
-@functools.partial(jax.jit, static_argnames=("n_heads", "control"))
-def logits(w: Dict, tokens: jax.Array, *, n_heads: int,
+def logits(w: Dict, tokens: jax.Array, *, conf: Dict,
            control: Optional[str] = None):
-    """Logits (S, V) at every position of one token sequence (S,)."""
+    """Logits (S, V) at every position of one token sequence (S,), for
+    the configuration ``conf`` (the reference reads its head count)."""
+    return _logits(w, tokens, n_heads=conf["num_attention_heads"],
+                   control=control)
+
+
+@functools.partial(jax.jit, static_argnames=("n_heads", "control"))
+def _logits(w: Dict, tokens: jax.Array, *, n_heads: int,
+            control: Optional[str] = None):
     s = tokens.shape[0]
     x = w["embed"][tokens] + w["pos"][jnp.arange(s)]
 

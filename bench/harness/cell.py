@@ -13,8 +13,7 @@ import numpy as np
 from bench.harness import check as check_lib
 from bench.harness import spec as spec_lib
 from bench.harness import trace as trace_lib
-from bench.harness import weights as weights_lib
-from bench.harness.serve import Run, configure_jax
+from bench.harness.serve import Run, configure_jax, seed_key
 from bench.harness.spans import CompileClock
 
 
@@ -26,7 +25,7 @@ class Context:
         self.records = run.records
         self.w0, self.w1 = run.w0, run.w1
         self.setup_s = run.w0 - run.t_start
-        self.sizes = run.conf
+        self.counts = run.counts            # the configuration's work counts
         self.peaks = run.peaks
         self.layer = run.layer
         self.spans = getattr(run, "spans", [])
@@ -86,14 +85,13 @@ def _correctness(run: Run, log, control: bool = False) -> Dict:
         return {"correct": False, "limits": limits,
                 "readings": {k: float("nan") for k in limits}}
     ref = run.bench.reference(run.conf["reference"])
-    w = run.make_weights(weights_lib.seed_key(run.seed))
-    heads = run.conf["num_attention_heads"]
+    w = run.make_weights(seed_key(run.seed))
 
     def logits_fn(tokens):
-        return ref.logits(w, jax.numpy.asarray(tokens), n_heads=heads)
+        return ref.logits(w, jax.numpy.asarray(tokens), conf=run.conf)
 
     def control_fn(tokens):
-        return ref.logits(w, jax.numpy.asarray(tokens), n_heads=heads,
+        return ref.logits(w, jax.numpy.asarray(tokens), conf=run.conf,
                           control=run.conf["control"])
 
     t0 = time.perf_counter()

@@ -43,7 +43,6 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from bench.harness import traffic as traffic_lib
-from bench.harness import weights as weights_lib
 
 CACHE_DIR = "bench/.jax_cache"          # relative to the checkout
 TRACE_DIR = "bench/.trace"
@@ -99,29 +98,11 @@ def configure_jax(root: str) -> str:
     return path
 
 
-def model_config(conf: Dict):
-    """The program's ``ModelConfig`` for a configuration file, checked to
-    hold exactly the file's sizes."""
-    from repro.configs import get_config
+def seed_key(seed: int):
+    """The key every weight of a run is drawn from."""
+    import jax
 
-    base = get_config(conf["program_arch"])
-    s = conf
-    cfg = dataclasses.replace(
-        base, n_layers=s["num_hidden_layers"], d_model=s["hidden_size"],
-        n_heads=s["num_attention_heads"],
-        n_kv_heads=s["num_attention_heads"], d_ff=s["ffn_dim"],
-        vocab_size=s["vocab_size"], max_seq=s["max_position_embeddings"],
-        dtype=conf["dtype"])
-    want = dict(pos_emb="learned", norm_kind="layernorm", mlp_kind="relu",
-                attn_bias=True, tie_embeddings=True, family="dense",
-                attn_kind="gqa")
-    for k, v in want.items():
-        if getattr(cfg, k) != v:
-            raise ValueError(f"{conf['program_arch']}: {k}={getattr(cfg, k)!r}"
-                             f" is not the OPT block ({v!r})")
-    if cfg.hd * cfg.n_heads != cfg.d_model:
-        raise ValueError("head size times heads must equal the hidden size")
-    return cfg
+    return jax.random.PRNGKey(int(seed))
 
 
 class Run:
@@ -133,6 +114,8 @@ class Run:
         self.bench = bench
         self.cell = bench.cell(cell_name)
         self.conf = bench.config(self.cell["config"])
+        self.arch = bench.arch(self.conf["arch"])
+        self.counts = self.arch.counts(self.conf)
         self.mix = bench.traffic(self.cell["traffic"])
         self.limits = bench.limits(cell_name)
         self.seed = int(seed)
@@ -179,10 +162,10 @@ class Run:
 
         conf, mix = self.conf, self.mix
         t0 = time.perf_counter()
-        self.cfg = model_config(conf)
-        self.make_weights = weights_lib.make_fn(conf, conf["init"])
-        w = self.make_weights(weights_lib.seed_key(self.seed))
-        params = weights_lib.program_params(w)
+        self.cfg = self.arch.program_config(conf)
+        self.make_weights = self.arch.make_weights(conf)
+        w = self.make_weights(seed_key(self.seed))
+        params = self.arch.program_params(w)
         del w
         self.log(f"build: weights on the host at +{time.perf_counter() - t0:.3f} s")
         self.backend = HeteGenBackend(

@@ -7,13 +7,15 @@ under the benchmark directory, and is found from the name that
 
     configs/<config>.json        sizes of a model configuration, as run
     configs/<reference>.py       its plain reference (named by the config)
+    configs/<arch>_arch.py       its block (named by the config): weights,
+                                 the program's mapping, the work counts
     traffic/<traffic>.json       parameters of a traffic mix
     metrics/<metric>.py          a metric's reader: ``read(ctx) -> value``
     limits/<cell>.json           the limits that decide ``correct``
     peaks/<device_kind>.json     a chip's published peaks (spaces -> "_")
 
-Adding a cell, a mix, a metric or a chip adds files and entries; no file
-that is already there changes.
+Adding a configuration, a cell, a mix, a metric or a chip adds files and
+entries; no file that is already there changes.
 """
 
 from __future__ import annotations
@@ -105,6 +107,14 @@ class Bench:
         """The plain reference module a configuration names."""
         return _load_module(self.bench_dir / "configs" / f"{_check_name(name)}.py",
                             f"bench_reference_{name.replace('.', '_')}")
+
+    def arch(self, name: str):
+        """The architecture module a configuration names: its
+        ``make_weights``, ``program_config``, ``program_params`` and
+        ``counts``."""
+        return _load_module(
+            self.bench_dir / "configs" / f"{_check_name(name)}_arch.py",
+            f"bench_arch_{name.replace('.', '_').replace('-', '_')}")
 
     def metric_reader(self, name: str) -> Callable:
         mod = _load_module(self.bench_dir / "metrics" / f"{_check_name(name)}.py",

@@ -14,9 +14,9 @@ def read(ctx):
     flops = 0.0
     for t0, _, b, s in ctx.layer.prefill:
         if ctx.in_window(t0):
-            flops += model_flops(ctx.sizes, b * s, b)
+            flops += model_flops(ctx.counts, b * s, b)
     for t0, _, rows, _ in ctx.layer.decode:
         if ctx.in_window(t0):
-            flops += model_flops(ctx.sizes, rows, rows)
+            flops += model_flops(ctx.counts, rows, rows)
     window = ctx.summary["window_s"]
     return 100.0 * flops / (window * ctx.peaks["bf16_flops_per_s"])

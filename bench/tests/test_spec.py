@@ -14,6 +14,8 @@ def test_the_benchmark_resolves_every_name_it_gives():
         conf = b.config(c["name"])
         assert (ROOT / c["file"]).is_file()
         b.reference(conf["reference"])
+        arch = b.arch(conf["arch"])
+        assert set(arch.counts(conf)) >= {"linear_params", "head_params"}
         for k in c["reduced"]:
             assert conf[k] != conf["published"][k]
     for w in b.spec["workloads"]:

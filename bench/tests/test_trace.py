@@ -103,14 +103,16 @@ def test_recorded_paged_decode_time_exceeds_its_least_time():
     of the 16 rows) the kernel's least time stays under its recorded
     time: the roofline share cannot pass 100%."""
     from bench.harness import flops
+    from bench.harness.spec import Bench
 
     t, w0, w1 = _recorded()
     secs, n = trace.kernel_time(t, w0, w1, _decode_pattern())
-    sizes = {"hidden_size": 5120}
+    b = Bench()
+    c = b.arch("opt").counts(b.config("opt-13b-fp-offload"))
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
     lens = [320] * 16
-    least = n * flops.roofline_seconds(flops.paged_decode_flops(sizes, lens),
-                                       flops.paged_decode_bytes(sizes, lens),
+    least = n * flops.roofline_seconds(flops.paged_decode_flops(c, lens),
+                                       flops.paged_decode_bytes(c, lens),
                                        peaks)
     assert 0 < least < secs
 
