@@ -61,6 +61,7 @@ def _ensure_loaded() -> None:
         minicpm3_4b,
         mistral_nemo_12b,
         nemotron_4_340b,
+        nemotron_h_47b,
         opt,
         tiny,
         whisper_small,

@@ -28,7 +28,7 @@ class LinearSpec:
     name: str
     n_in: int
     n_out: int
-    group: str                  # "attn" | "mlp" | ... (pin-ring size group)
+    group: str                  # "attn", "mlp", "ssm_in", ... (pin ring)
     dtype_bytes: int = 4
     calls: int = 1              # invocations per decode step (shared blocks)
     wire: str = "fp"            # streamed format: "fp" | "q8" (int8+scales)

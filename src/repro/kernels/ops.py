@@ -28,6 +28,7 @@ from repro.kernels import q8_matmul as _q8
 from repro.kernels import ref as _ref
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd_chunk as _ssd
+from repro.kernels import ssm_step as _ssm_step
 
 _MODE: Optional[str] = None
 
@@ -134,3 +135,19 @@ def ssd_chunk(x, dt, a, b, c, *, chunk, **kw):
         return _ref.ssd_chunk(x, dt, a, b, c, chunk=chunk)
     return _ssd.ssd_chunk(x, dt, a, b, c, chunk=chunk,
                           interpret=(m == "interpret"), **kw)
+
+
+def ssm_conv_step(conv_pool, slots, x, w, bias, **kw):
+    m = _mode()
+    if m == "ref":
+        return _ref.ssm_conv_step(conv_pool, slots, x, w, bias)
+    return _ssm_step.conv_step(conv_pool, slots, x, w, bias,
+                               interpret=(m == "interpret"), **kw)
+
+
+def ssm_scan_step(ssm_pool, slots, x, dt, b, c, a, d, **kw):
+    m = _mode()
+    if m == "ref":
+        return _ref.ssm_scan_step(ssm_pool, slots, x, dt, b, c, a, d)
+    return _ssm_step.scan_step(ssm_pool, slots, x, dt, b, c, a, d,
+                               interpret=(m == "interpret"), **kw)

@@ -197,3 +197,28 @@ def ssd_chunk(x, dt, a, b, c, *, chunk: int
     sc = jnp.einsum("bnkh,bnkhs,bnkhp->bnhps", tail, bc, xdt)
     return (y.reshape(bs, ln, h, p).astype(x.dtype), sc,
             cum.reshape(bs, ln, h))
+
+
+def ssm_conv_step(conv_pool, slots, x, w, bias):
+    """Reference of :func:`repro.kernels.ssm_step.conv_step`: gather the
+    slots' conv windows, convolve the new input, scatter the shifted
+    windows back."""
+    win = conv_pool[slots].astype(jnp.float32)            # (B, K-1, C)
+    full = jnp.concatenate([win, x[:, None].astype(jnp.float32)], axis=1)
+    y = jnp.einsum("bkc,kc->bc", full, w.astype(jnp.float32)) + bias
+    pool = conv_pool.at[slots].set(full[:, 1:].astype(conv_pool.dtype))
+    return jax.nn.silu(y).astype(x.dtype), pool
+
+
+def ssm_scan_step(ssm_pool, slots, x, dt, b, c, a, d):
+    """Reference of :func:`repro.kernels.ssm_step.scan_step`: one SSM
+    recurrence step per head on the gathered slot rows."""
+    h = ssm_pool[slots]                                   # (B, H, P, N)
+    dt = dt.astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    decay = jnp.exp(dt * a)[..., None, None]
+    hnew = h * decay + (dt[..., None] * xf)[..., None] \
+        * b.astype(jnp.float32)[:, :, None, :]
+    y = jnp.einsum("bhpn,bhn->bhp", hnew, c.astype(jnp.float32)) \
+        + xf * d[:, None]
+    return y.astype(x.dtype), ssm_pool.at[slots].set(hnew)

@@ -14,6 +14,10 @@ import math
 from typing import Optional, Tuple
 
 
+# block_pattern letters (Nemotron-H's ``hybrid_override_pattern``)
+PATTERN_KINDS = {"M": "mamba", "*": "attn", "-": "mlp"}
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "tiny"
@@ -68,6 +72,10 @@ class ModelConfig:
     # --- hybrid (zamba2: shared attention block over a mamba trunk) ---
     shared_attn_period: int = 0     # apply the shared block every k layers
     shared_lora_rank: int = 0       # per-invocation LoRA on the shared block
+    # --- hybrid by block pattern (nemotron-h: one mixer per layer) ---
+    block_pattern: Optional[str] = None  # per layer: "M" Mamba2 mixer,
+                                         # "*" attention, "-" MLP; each
+                                         # layer is x + mixer(norm(x))
 
     # --- encoder-decoder (whisper) ---
     encoder_layers: int = 0
@@ -111,6 +119,11 @@ class ModelConfig:
         return self.ssm_expand * self.d_model
 
     @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the Mamba2 causal conv: x, B and C."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim if self.ssm_state else 0
 
@@ -125,7 +138,10 @@ class ModelConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer kind string, e.g. ('local','global',...) for gemma2
-        or ('moe','dense',...) for maverick."""
+        or ('moe','dense',...) for maverick; a block pattern gives
+        ('mamba','attn','mlp',...)."""
+        if self.block_pattern:
+            return tuple(PATTERN_KINDS[c] for c in self.block_pattern)
         kinds = []
         for i in range(self.n_layers):
             if self.family == "ssm" or self.family == "hybrid":
@@ -191,6 +207,10 @@ class ModelConfig:
         for kind in self.layer_kinds():
             if kind == "mamba":
                 total += mamba_params() + d              # pre-norm
+            elif kind == "attn":                         # pattern layers:
+                total += attn_params() + d               # one mixer, one
+            elif kind == "mlp":                          # pre-norm each
+                total += mlp_params(f) + d
             elif kind == "moe":
                 total += attn_params() + norms_per_block
                 total += d * self.n_experts              # router
@@ -241,6 +261,13 @@ class ModelConfig:
 def kv_cache_bytes(cfg: ModelConfig, batch: int, seq: int) -> int:
     """KV-cache footprint for decode at (batch, seq)."""
     by = cfg.dtype_bytes()
+    if cfg.block_pattern:
+        kinds = cfg.layer_kinds()
+        state = cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
+        conv = cfg.ssm_conv_dim * (cfg.ssm_conv - 1)
+        per_tok = 2 * cfg.n_kv_heads * cfg.hd * by
+        return (kinds.count("mamba") * batch * (state + conv) * 4
+                + kinds.count("attn") * batch * seq * per_tok)
     if cfg.family == "ssm":
         state = cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state
         conv = (cfg.d_inner + 2 * cfg.ssm_groups * cfg.ssm_state) * cfg.ssm_conv

@@ -7,7 +7,10 @@ Families:
                      (local, global) pair, maverick's (dense, moe) pair)
     ssm              Mamba2 trunk (attention-free)
     hybrid           zamba2: Mamba2 trunk + one shared attention block
-                     (invoked every k layers with per-site LoRA)
+                     (invoked every k layers with per-site LoRA); or, with
+                     ``block_pattern`` (nemotron-h), one mixer per layer —
+                     Mamba2, attention or MLP — served only through the
+                     backend seam (``backend_prefill``) on a paged cache
     encdec           whisper: stub-frontend encoder + causal decoder with
                      cross attention
     vlm              llava: dense backbone whose prefill consumes
@@ -160,6 +163,45 @@ def _init_mamba(cfg, key) -> Dict:
     }
 
 
+def _init_pattern_mamba(cfg, key) -> Dict:
+    """A block-pattern Mamba2 mixer (nemotron-h layout): one fused
+    ``in_proj`` emitting [z | x B C | dt], a depthwise conv over the x B C
+    channels with bias, per-head A/D/dt bias, and a gated RMSNorm scale.
+    A, D and the dt bias are drawn around the published initialization
+    (A in [-16, -1], dt in [1e-3, 1e-1] after softplus) so every term of
+    the recurrence is exercised."""
+    d, dt = cfg.d_model, _dtype(cfg)
+    din, h = cfg.d_inner, cfg.ssm_heads
+    conv_dim = cfg.ssm_conv_dim
+    ks = jax.random.split(key, 8)
+    step = jnp.exp(jax.random.uniform(ks[3], (h,), jnp.float32,
+                                      math.log(1e-3), math.log(1e-1)))
+    return {
+        "in_proj": _dense(ks[0], (d, din + conv_dim + h), dt),
+        "conv_w": _dense(ks[1], (cfg.ssm_conv, conv_dim), dt, scale=0.5),
+        "conv_b": _dense(ks[2], (conv_dim,), dt, scale=0.1),
+        "A_log": jnp.log(jax.random.uniform(ks[4], (h,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": 1.0 + 0.1 * jax.random.normal(ks[5], (h,), jnp.float32),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),   # softplus^-1
+        "gnorm": (1.0 + 0.1 * jax.random.normal(ks[6], (din,))).astype(dt),
+        "out_proj": _dense(ks[7], (din, d), dt),
+    }
+
+
+def _init_pattern_layer(cfg, key, kind: str) -> Dict:
+    """One block-pattern layer: a pre-norm and its one mixer."""
+    ks = jax.random.split(key, 2)
+    p: Dict = {"norm": _init_norm(cfg, ks[0], cfg.d_model)}
+    if kind == "mamba":
+        p["mixer"] = _init_pattern_mamba(cfg, ks[1])
+    elif kind == "attn":
+        p["attn"] = _init_attn(cfg, ks[1])
+    else:
+        p["mlp"] = _init_mlp(cfg, ks[1])
+    return p
+
+
 def _init_block(cfg, key, kind: str) -> Dict:
     """One layer of a given kind."""
     ks = jax.random.split(key, 6)
@@ -199,6 +241,12 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
                                scale=0.02)
 
     kinds = cfg.layer_kinds()
+    if cfg.block_pattern:
+        # layers differ in kind, so they are a list, not a scanned stack
+        lkeys = jax.random.split(keys[4], cfg.n_layers)
+        params["layers"] = [_init_pattern_layer(cfg, k, kind)
+                            for k, kind in zip(lkeys, kinds)]
+        return params
     if cfg.family in ("ssm", "hybrid"):
         period = cfg.shared_attn_period or cfg.n_layers
         n_groups = cfg.n_layers // period
@@ -258,6 +306,15 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
     return params
 
 
+def _refuse_pattern(cfg: ModelConfig, what: str) -> None:
+    """Block-pattern hybrids run only through the backend seam; every
+    other path would run zamba2's shared-attention trunk instead."""
+    if cfg.block_pattern:
+        raise NotImplementedError(
+            f"{what} does not run block-pattern hybrids ({cfg.name}); "
+            "serve them through the backend seam on a paged cache")
+
+
 def _pattern_period(cfg: ModelConfig) -> int:
     if cfg.family in ("ssm", "hybrid"):
         return 1
@@ -275,6 +332,7 @@ def _pattern_period(cfg: ModelConfig) -> int:
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                spec_only: bool = False) -> Dict:
     """Cache pytree (jnp zeros, or ShapeDtypeStructs when ``spec_only``)."""
+    _refuse_pattern(cfg, "the scan-stacked cache")
     dt = _dtype(cfg)
 
     def mk(shape, dtype=dt):
@@ -976,6 +1034,7 @@ def forward_train(cfg: ModelConfig, params: Dict, batch: Dict,
     ``batch`` carries "tokens" and, for stub-frontend families, "embeds"
     (vlm: replaces token embeddings; encdec: encoder frames).
     """
+    _refuse_pattern(cfg, "forward_train")
     if cfg.embeds_input and "embeds" in batch:
         x = batch["embeds"].astype(_dtype(cfg))
         b, s = x.shape[:2]
@@ -1124,6 +1183,7 @@ def prefill(cfg: ModelConfig, params: Dict, batch: Dict, cache: Dict,
     instead of (B, V) — the speculative-verify shape, where one
     prefill-shaped pass must score each draft position's next-token
     distribution."""
+    _refuse_pattern(cfg, "the scan-stacked prefill")
     if cfg.embeds_input and "embeds" in batch:
         x = batch["embeds"].astype(_dtype(cfg))
         b, s = x.shape[:2]
@@ -1234,10 +1294,21 @@ def make_backend_ops(cfg: ModelConfig) -> Dict:
     def _logits(shared, x):
         return lm_logits(cfg, shared, x)
 
-    return {"norm": jax.jit(partial(L.apply_norm, cfg)),
-            "attend": jax.jit(_attend, static_argnums=(5,)),
-            "paged_attend": jax.jit(_paged, static_argnums=(6,)),
-            "logits": jax.jit(_logits)}
+    ops = {"norm": jax.jit(partial(L.apply_norm, cfg)),
+           "attend": jax.jit(_attend, static_argnums=(5,)),
+           "paged_attend": jax.jit(_paged, static_argnums=(6,)),
+           "logits": jax.jit(_logits)}
+    if cfg.block_pattern:
+        # the Mamba2 mixer's device pieces; each updates its state pools
+        # in place (donated), so a step never copies a pool
+        ops.update(
+            ssm_conv=jax.jit(partial(ssm_prefill_conv, cfg),
+                             donate_argnums=(2,)),
+            ssm_scan=jax.jit(partial(ssm_prefill_scan, cfg),
+                             donate_argnums=(3,)),
+            ssm_step=jax.jit(partial(ssm_state_step, cfg),
+                             donate_argnums=(2, 3)))
+    return ops
 
 
 def extract_backend_params(cfg: ModelConfig, params: Dict):
@@ -1248,7 +1319,13 @@ def extract_backend_params(cfg: ModelConfig, params: Dict):
     keeps everything the layer math reads directly (embeddings, norms,
     qk-norm scales, lm head) plus per-layer small-param dicts under
     "layers".
+
+    Block-pattern hybrids (nemotron-h) keep their per-layer list: a Mamba
+    layer contributes "blk{l}.in_proj"/"blk{l}.out_proj" and keeps its
+    conv, A, D, dt bias and gated-norm scale in ``shared``.
     """
+    if cfg.block_pattern:
+        return _extract_pattern_params(cfg, params)
     if cfg.family not in ("dense", "vlm") or cfg.attn_kind != "gqa":
         raise NotImplementedError(
             "backend execution supports dense GQA decoders "
@@ -1292,12 +1369,189 @@ def extract_backend_params(cfg: ModelConfig, params: Dict):
     return shared, weights, biases
 
 
+def _extract_pattern_params(cfg: ModelConfig, params: Dict):
+    if cfg.attn_kind != "gqa" or cfg.attn_bias or cfg.qk_norm \
+            or cfg.post_norm or cfg.mlp_kind.startswith("gated"):
+        raise NotImplementedError(
+            f"{cfg.name}: block-pattern layers take GQA attention and a "
+            "plain MLP with no biases, qk-norm or post-norms")
+    shared: Dict = {"embed": params["embed"],
+                    "final_norm": params["final_norm"]}
+    if "lm_head" in params:
+        shared["lm_head"] = params["lm_head"]
+    weights: Dict = {}
+    layers = []
+    for l, (kind, blk) in enumerate(zip(cfg.layer_kinds(),
+                                        params["layers"])):
+        small: Dict = {"norm": blk["norm"]}
+        if kind == "mamba":
+            m = blk["mixer"]
+            weights[f"blk{l}.in_proj"] = m["in_proj"]
+            weights[f"blk{l}.out_proj"] = m["out_proj"]
+            small["mixer"] = {k: v for k, v in m.items()
+                              if k not in ("in_proj", "out_proj")}
+        elif kind == "attn":
+            for nm in ("wq", "wk", "wv", "wo"):
+                weights[f"blk{l}.{nm}"] = blk["attn"][nm]
+        else:
+            for nm in ("w_in", "w_down"):
+                weights[f"blk{l}.{nm}"] = blk["mlp"][nm]
+        layers.append(small)
+    shared["layers"] = layers
+    return shared, weights, {}
+
+
+# -- the Mamba2 mixer of block-pattern layers, on the device ----------------
+#
+# ``in_proj`` and ``out_proj`` run through the backend's ``linear`` (the
+# HeteGen split); what lies between them runs here, against two per-slot
+# state pools (:mod:`repro.serving.kv_cache`): ``conv_pool`` (S, K-1, C)
+# holds each slot's last K-1 conv inputs and ``ssm_pool`` (S, H, P, N) its
+# SSM state.  ``slots`` (B,) names the pool row of each batch row; the
+# functions read those rows and write them back, nothing else.
+
+def _ssm_dims(cfg):
+    gn = cfg.ssm_groups * cfg.ssm_state
+    return cfg.d_inner, cfg.ssm_conv_dim, gn
+
+
+def _ssm_split(cfg, zxbcdt):
+    """[z | x B C | dt] as in_proj emits it."""
+    din, cd, _ = _ssm_dims(cfg)
+    return (zxbcdt[..., :din], zxbcdt[..., din:din + cd],
+            zxbcdt[..., din + cd:])
+
+
+def _gated_norm(cfg, y, z, scale):
+    """RMSNorm of y * silu(z) over each group of d_inner / ssm_groups
+    channels (the gate before the norm), times ``scale``."""
+    yz = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    shp = yz.shape
+    yz = yz.reshape(shp[:-1] + (cfg.ssm_groups, shp[-1] // cfg.ssm_groups))
+    var = jnp.mean(jnp.square(yz), axis=-1, keepdims=True)
+    yz = (yz * jax.lax.rsqrt(var + cfg.norm_eps)).reshape(shp)
+    return (yz * scale.astype(jnp.float32)).astype(y.dtype)
+
+
+def ssm_prefill_conv(cfg, p, zxbcdt, conv_pool, slots):
+    """Causal conv over a prompt chunk (B, s, ...) from each slot's conv
+    state; returns (silu(conv) of the x B C channels, updated pool)."""
+    _, xbc, _ = _ssm_split(cfg, zxbcdt)
+    y, new = S._causal_conv(xbc, p["conv_w"], p["conv_b"],
+                            conv_pool[slots].astype(xbc.dtype))
+    return y, conv_pool.at[slots].set(new.astype(conv_pool.dtype))
+
+
+def ssm_prefill_scan(cfg, p, zxbcdt, xbc, ssm_pool, slots):
+    """SSD over a prompt chunk from each slot's SSM state (chunked form,
+    the chunk padded at its end with dt = 0 steps, which leave the state
+    as it is); returns (gated-normed y (B, s, d_inner), updated pool)."""
+    z, _, dt = _ssm_split(cfg, zxbcdt)
+    din, _, gn = _ssm_dims(cfg)
+    b, s = xbc.shape[:2]
+    h, pd, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, \
+        cfg.ssm_state
+    x = xbc[..., :din].reshape(b, s, h, pd)
+    bm = xbc[..., din:din + gn].reshape(b, s, g, n)
+    cm = xbc[..., din + gn:].reshape(b, s, g, n)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    pad = (-s) % cfg.ssm_chunk
+    if pad:
+        def tail(a):
+            return jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        x, dt, bm, cm = tail(x), tail(dt), tail(bm), tail(cm)
+    y, h_n = S.ssd_chunked(x, dt, -jnp.exp(p["A_log"]), bm, cm, p["D"],
+                           chunk=cfg.ssm_chunk, h0=ssm_pool[slots])
+    y = y[:, :s].reshape(b, s, din)
+    return (_gated_norm(cfg, y, z, p["gnorm"]),
+            ssm_pool.at[slots].set(h_n))
+
+
+def ssm_state_step(cfg, p, zxbcdt, conv_pool, ssm_pool, slots):
+    """One decode token (B, 1, ...) through conv and SSM, each slot's
+    state updated in place (kernels ``ssm_state_conv`` and
+    ``ssm_state_scan``); returns (gated-normed y (B, 1, d_inner), conv
+    pool, SSM pool)."""
+    from repro.kernels import ops as K
+
+    z, xbc, dt = _ssm_split(cfg, zxbcdt[:, 0])
+    din, _, gn = _ssm_dims(cfg)
+    b = xbc.shape[0]
+    h, pd, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, \
+        cfg.ssm_state
+    xbc, conv_pool = K.ssm_conv_step(conv_pool, slots, xbc, p["conv_w"],
+                                     p["conv_b"])
+    rep = h // g
+    bm = jnp.repeat(xbc[:, din:din + gn].reshape(b, g, n), rep, axis=1)
+    cm = jnp.repeat(xbc[:, din + gn:].reshape(b, g, n), rep, axis=1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    y, ssm_pool = K.ssm_scan_step(ssm_pool, slots,
+                                  xbc[:, :din].reshape(b, h, pd), dt, bm,
+                                  cm, -jnp.exp(p["A_log"]), p["D"])
+    y = _gated_norm(cfg, y.reshape(b, din), z, p["gnorm"])
+    return y[:, None], conv_pool, ssm_pool
+
+
+def _mamba_mixer(cfg, p, h, conv_pool, ssm_pool, slots, linear, ops):
+    """in_proj (backend) -> conv + SSD (device) -> out_proj (backend)."""
+    zxbcdt = linear(h, "in_proj")
+    if h.shape[1] == 1:
+        step = ops.get("ssm_step") or functools.partial(ssm_state_step, cfg)
+        y, conv_pool, ssm_pool = step(p, zxbcdt, conv_pool, ssm_pool, slots)
+    else:
+        conv = ops.get("ssm_conv") \
+            or functools.partial(ssm_prefill_conv, cfg)
+        scan = ops.get("ssm_scan") \
+            or functools.partial(ssm_prefill_scan, cfg)
+        xbc, conv_pool = conv(p, zxbcdt, conv_pool, slots)
+        y, ssm_pool = scan(p, zxbcdt, xbc, ssm_pool, slots)
+    return linear(y, "out_proj"), conv_pool, ssm_pool
+
+
+def _pattern_layers(cfg, shared, x, positions, cache, cur_len, *, linear,
+                    ops):
+    """Run every block-pattern layer: x + mixer(norm(x)), the mixer a
+    Mamba2 mixer, paged GQA attention or an MLP.  Returns (x, cache)."""
+    if "block_tables" not in cache:
+        raise NotImplementedError(
+            f"{cfg.name}: block-pattern layers run on a paged cache only "
+            "(PagedKVCache holds their pages and per-slot state)")
+    new_cache = dict(cache)
+    norm = ops.get("norm") or (lambda pp, h: L.apply_norm(cfg, pp, h))
+    slots = cache["slot_rows"]
+    for l, kind in enumerate(cfg.layer_kinds()):
+        p = shared["layers"][l]
+        lin = (lambda h, nm, _l=l: linear(h, f"blk{_l}.{nm}"))
+        if kind == "attn":
+            x, kv = _apply_attn_layer(
+                cfg, {"ln1": p["norm"], "attn": {}}, x, positions,
+                kind="global", kv_cache=(cache[f"pages_k{l}"],
+                                         cache[f"pages_v{l}"]),
+                cur_len=cur_len, linear=lin, norm_fn=ops.get("norm"),
+                block_tables=cache["block_tables"],
+                paged_attend_fn=ops.get("paged_attend"))
+            new_cache[f"pages_k{l}"], new_cache[f"pages_v{l}"] = kv
+        elif kind == "mlp":
+            x = _apply_ffn(cfg, {"ln2": p["norm"], "mlp": {}}, x, "dense",
+                           NO_RULES, linear=lin, norm_fn=ops.get("norm"))
+        else:
+            y, conv, ssm = _mamba_mixer(
+                cfg, p["mixer"], norm(p["norm"], x),
+                cache[f"state_conv{l}"], cache[f"state_ssm{l}"], slots,
+                lin, ops)
+            new_cache[f"state_conv{l}"], new_cache[f"state_ssm{l}"] = \
+                conv, ssm
+            x = x + y
+    return x, new_cache
+
+
 def init_backend_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
     """Per-layer KV cache for backend execution: "k{l}"/"v{l}" buffers in
     (B, T, Hkv, hd) layout plus "len" (scalar; continuous batching replaces
     it with a (B,) per-slot vector).  Batch lives on axis 0 of every
     buffer.  The paged alternative (no dense (B, T) buffers) is minted by
     :meth:`repro.serving.kv_cache.PagedKVCache.init_cache`."""
+    _refuse_pattern(cfg, "the dense backend cache")
     dt = _dtype(cfg)
     cache: Dict = {"len": jnp.zeros((), jnp.int32)}
     for l in range(cfg.n_layers):
@@ -1333,6 +1587,11 @@ def backend_prefill(cfg: ModelConfig, shared: Dict, batch: Dict, cache: Dict,
     cur_len = cache["len"]
     positions = _positions_from(cur_len, b, s)
     x = _add_learned_pos(cfg, shared, x, positions)
+    if cfg.block_pattern:
+        x, new_cache = _pattern_layers(cfg, shared, x, positions, cache,
+                                       cur_len, linear=linear, ops=ops)
+        return _backend_head(cfg, shared, x, new_cache, cur_len + s, ops,
+                             all_logits)
     kinds = cfg.layer_kinds()
     new_cache = dict(cache)
     paged = "pages_k0" in cache         # paged pools instead of dense bufs
@@ -1357,7 +1616,13 @@ def backend_prefill(cfg: ModelConfig, shared: Dict, batch: Dict, cache: Dict,
                  new_cache[f"pages_vs{l}"]) = kv[2:]
         else:
             new_cache[f"k{l}"], new_cache[f"v{l}"] = kv
-    new_cache["len"] = cur_len + s
+    return _backend_head(cfg, shared, x, new_cache, cur_len + s, ops,
+                         all_logits)
+
+
+def _backend_head(cfg, shared, x, new_cache, new_len, ops, all_logits):
+    """Final norm and head after the layers; sets the cache length."""
+    new_cache["len"] = new_len
     norm = ops.get("norm") or (lambda pp, h: L.apply_norm(cfg, pp, h))
     x = norm(shared["final_norm"], x if all_logits else x[:, -1:])
     if "logits" in ops:

@@ -322,7 +322,10 @@ class LLM:
                 and not any(r.sampling.logprobs is not None for r in reqs)
                 # speculative decoding is a batcher feature (draft →
                 # verify → rollback lives in its step loop)
-                and self.spec is None)
+                and self.spec is None
+                # block-pattern hybrids keep their Mamba state in the
+                # batcher's paged cache; the one-shot path has none
+                and not self.cfg.block_pattern)
         if rect and not busy:
             return self._generate_oneshot(reqs)
         return self._generate_batched(reqs)

@@ -106,6 +106,8 @@ def enumerate_linears(cfg: ModelConfig,
     def spec(name, n_in, n_out, group):
         return LinearSpec(name, n_in, n_out, group, by, wire=ws)
 
+    if cfg.block_pattern:
+        return _pattern_linears(cfg, spec)
     out = []
     for l in range(cfg.n_layers):
         out += [
@@ -118,6 +120,29 @@ def enumerate_linears(cfg: ModelConfig,
             out += [spec(f"blk{l}.w_gate", d, f, "mlp"),
                     spec(f"blk{l}.w_up", d, f, "mlp"),
                     spec(f"blk{l}.w_down", f, d, "mlp_down")]
+        else:
+            out += [spec(f"blk{l}.w_in", d, f, "mlp"),
+                    spec(f"blk{l}.w_down", f, d, "mlp_down")]
+    return out
+
+
+def _pattern_linears(cfg: ModelConfig, spec) -> List[LinearSpec]:
+    """A block-pattern layer holds one mixer: a Mamba layer its in_proj
+    ([z | x B C | dt]) and out_proj, each a size group of its own; an
+    attention layer q, k, v, o; an MLP layer in and down."""
+    d, f = cfg.d_model, cfg.d_ff
+    hd, hq, hkv = cfg.hd, cfg.n_heads, cfg.n_kv_heads
+    din, h, conv_dim = cfg.d_inner, cfg.ssm_heads, cfg.ssm_conv_dim
+    out = []
+    for l, kind in enumerate(cfg.layer_kinds()):
+        if kind == "mamba":
+            out += [spec(f"blk{l}.in_proj", d, din + conv_dim + h, "ssm_in"),
+                    spec(f"blk{l}.out_proj", din, d, "ssm_out")]
+        elif kind == "attn":
+            out += [spec(f"blk{l}.wq", d, hq * hd, "attn"),
+                    spec(f"blk{l}.wk", d, hkv * hd, "attn_kv"),
+                    spec(f"blk{l}.wv", d, hkv * hd, "attn_kv"),
+                    spec(f"blk{l}.wo", hq * hd, d, "attn")]
         else:
             out += [spec(f"blk{l}.w_in", d, f, "mlp"),
                     spec(f"blk{l}.w_down", f, d, "mlp_down")]
@@ -223,6 +248,11 @@ class ScanResidentBackend:
     cache_batch_axis = 1
 
     def __init__(self, cfg: ModelConfig, params: Dict):
+        if cfg.block_pattern:
+            raise NotImplementedError(
+                f"{cfg.name}: the scan-stacked trunk does not run "
+                "block-pattern hybrids; use HeteGenBackend or "
+                "ResidentBackend with a paged cache")
         self.cfg = cfg
         self.params = params
 
@@ -313,6 +343,10 @@ class HeteGenBackend:
                               for k, v in weights.items()}
         self._host_biases = {k: _np(v) for k, v in biases.items()}
         self._ops = M.make_backend_ops(cfg)   # jitted norms/attention/head
+        for key, name in (("ssm_conv", "conv"), ("ssm_scan", "scan"),
+                          ("ssm_step", "state_step")):
+            if key in self._ops:
+                self._ops[key] = self._ssm_span(name, self._ops[key])
         self.wstream = wstream
         self.linears = enumerate_linears(cfg, wstream=wstream)
         # the planning rig follows the device the engines run on
@@ -444,6 +478,33 @@ class HeteGenBackend:
             if cur.intensity / f <= intensity <= cur.intensity * f:
                 return
         self.retune(batch, phase="verify", tokens_per_seq=seq)
+
+    def _ssm_span(self, name: str, fn):
+        """``fn`` (a Mamba mixer's device piece) inside a span on the
+        ``ssm`` track that lasts until its result is on the device:
+        ``conv`` and ``scan`` (prefill; ``rows``, ``tokens``) and
+        ``state_step`` (decode; ``rows``, and ``state_bytes``: each row's
+        conv and SSM state read and written once)."""
+        cfg = self.cfg
+        row_bytes = 2 * 4 * ((cfg.ssm_conv - 1) * cfg.ssm_conv_dim
+                             + cfg.ssm_heads * cfg.ssm_head_dim
+                             * cfg.ssm_state)
+
+        def call(p, zxbcdt, *rest):
+            b, s = zxbcdt.shape[:2]
+            attrs = ({"rows": b, "state_bytes": b * row_bytes}
+                     if name == "state_step" else {"rows": b, "tokens": b * s})
+            with self.tracer.span(name, track="ssm", phase=self._phase,
+                                  **attrs):
+                out = fn(p, zxbcdt, *rest)
+                # lint: allow[hot-path-sync] the out_proj linear that
+                # follows reads this result at once (its host share moves
+                # it to the host); waiting here puts the device time of
+                # the state update inside its own span
+                jax.block_until_ready(out)
+            return out
+
+        return call
 
     # -- tracing + trace-driven recalibration --------------------------
     def set_tracer(self, tracer: Tracer) -> None:

@@ -76,7 +76,7 @@ import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.serving.backends import ScanResidentBackend
-from repro.serving.kv_cache import slot_view
+from repro.serving.kv_cache import pool_keys, pool_view, slot_view
 from repro.serving.sampling import (SamplerConfig, SamplingParams, greedy,
                                     pack_sampling, request_key, sample_rows,
                                     step_key)
@@ -109,9 +109,33 @@ class ContinuousBatcher:
                  selfcheck: bool = False,
                  tracer: Tracer = NULL_TRACER,
                  metrics: Optional[MetricsRegistry] = None):
-        if cfg.family in ("ssm", "hybrid", "encdec"):
+        pattern = bool(cfg.block_pattern)
+        if cfg.family in ("ssm", "hybrid", "encdec") and not pattern:
             raise NotImplementedError(
-                "continuous batching supports transformer KV caches")
+                "continuous batching supports transformer KV caches and "
+                "block-pattern hybrids")
+        if pattern:
+            # a block-pattern hybrid's Mamba layers carry per-slot
+            # recurrent state beside the attention pages: it lives in the
+            # paged cache, is rebuilt by recompute after a preemption (a
+            # swap saves pages only), and cannot be aliased by prefix
+            # dedupe or rolled back by speculative decoding
+            if not paged:
+                raise NotImplementedError(
+                    f"{cfg.name}: block-pattern hybrids need paged=True")
+            if preempt_mode == "swap":
+                raise NotImplementedError(
+                    f"{cfg.name}: swap-mode preemption saves KV pages, not "
+                    "the Mamba state; use preempt_mode='recompute'")
+            if prefix_dedupe:
+                raise NotImplementedError(
+                    f"{cfg.name}: prefix dedupe would share pages without "
+                    "the Mamba state of the shared prefix")
+            if spec is not None:
+                raise NotImplementedError(
+                    f"{cfg.name}: speculative decoding cannot roll back "
+                    "the Mamba state of rejected drafts")
+            preempt_mode, prefix_dedupe = "recompute", False
         if backend is None and params is None:
             raise ValueError("ContinuousBatcher needs params or a backend")
         self.cfg = cfg
@@ -323,6 +347,7 @@ class ContinuousBatcher:
             return
         toks = jnp.asarray([st.prompt + st.generated], jnp.int32)
         if self.paged:
+            self._reset_state(slot)
             logits = self._prefill_paged_slot(slot, toks)
         else:
             logits = self._prefill_dense_slot(slot, toks)
@@ -364,10 +389,16 @@ class ContinuousBatcher:
         self.scheduler.tables_dirty = False
         one = slot_view(self.cache, slot)
         one, logits = self.backend.prefill({"tokens": toks}, one)
-        for key in one:
-            if key.startswith("pages_"):
-                self.cache[key] = one[key]
+        for key in pool_keys(one):
+            self.cache[key] = one[key]
         return logits
+
+    def _reset_state(self, slot: int) -> None:
+        """Zero the slot's recurrent state rows as it admits a sequence
+        (a no-op for caches that hold pages only)."""
+        if "slot_rows" in self.cache:
+            self.cache = self.kv.reset_state(self.cache, slot)
+            self.metrics.counter("serve.state_resets").inc()
 
     def _start_batch(self, sts: List[RequestState]) -> None:
         """Admit several same-length fresh requests in ONE prefill call
@@ -383,15 +414,13 @@ class ContinuousBatcher:
         if self.paged:
             self.cache["block_tables"] = self.kv.device_block_tables()
             self.scheduler.tables_dirty = False
-            view = {k: v for k, v in self.cache.items()
-                    if k.startswith("pages_")}
-            view["block_tables"] = self.cache["block_tables"][
-                jnp.asarray(slots)]
+            for slot in slots:
+                self._reset_state(slot)
+            view = pool_view(self.cache, jnp.asarray(slots))
             view["len"] = jnp.zeros((), jnp.int32)
             view, logits = self.backend.prefill({"tokens": toks}, view)
-            for key in view:
-                if key.startswith("pages_"):
-                    self.cache[key] = view[key]
+            for key in pool_keys(view):
+                self.cache[key] = view[key]
         else:
             axis = self.backend.cache_batch_axis
             grp = self.backend.init_cache(len(sts), self.max_len)
@@ -431,11 +460,12 @@ class ContinuousBatcher:
         if self.paged:
             self.cache["block_tables"] = self.kv.device_block_tables()
             self.scheduler.tables_dirty = False
+            if start == 0:
+                self._reset_state(slot)
             one = slot_view(self.cache, slot, length=start)
             one, logits = self.backend.prefill({"tokens": toks}, one)
-            for key in one:
-                if key.startswith("pages_"):
-                    self.cache[key] = one[key]
+            for key in pool_keys(one):
+                self.cache[key] = one[key]
         else:
             one_cache = self._pending_dense.get(slot)
             if one_cache is None:
@@ -754,15 +784,12 @@ class ContinuousBatcher:
         """
         slots = np.flatnonzero(active)
         idx = jnp.asarray(slots)
-        sub = {k: v for k, v in self.cache.items()
-               if k.startswith("pages_")}
-        sub["block_tables"] = self.cache["block_tables"][idx]
+        sub = pool_view(self.cache, idx)
         sub["len"] = self.cache["len"][idx]
         sub, logits = self.backend.decode(self.tokens[idx], sub)
         self._prefetch_next_step()
-        for key in sub:
-            if key.startswith("pages_"):
-                self.cache[key] = sub[key]
+        for key in pool_keys(sub):
+            self.cache[key] = sub[key]
         self.cache["len"] = self.cache["len"].at[idx].set(sub["len"])
         nxt = self._sample_slot_rows(logits, list(slots))
         self.tokens = self.tokens.at[idx].set(nxt)

@@ -33,6 +33,18 @@ Page id 0 is a reserved trash page: unmapped block-table entries point at
 it, so the masked garbage writes of inactive batcher slots land somewhere
 harmless instead of in another slot's pages.
 
+Block-pattern hybrids (nemotron-h) keep a second kind of per-sequence
+state beside the pages: each Mamba layer's recurrent state, one row per
+slot — "state_conv{l}" (max_slots, K-1, conv channels), the conv's last
+inputs, and "state_ssm{l}" (max_slots, H, P, N), the SSM state, both
+float32 — while only the attention layers get page pools.
+"slot_rows" names the pool row of each batch row (every row of the full
+cache; a view carries its own rows' slots).  A row is zeroed when its
+slot admits a sequence (:meth:`PagedKVCache.reset_state`, counted in
+``state_resets``), is never aliased by prefix dedupe or copied by
+fork/truncate (which touch pages only), and is simply left behind when
+the slot is released.
+
 Layout decision (recorded for ROADMAP): page_size defaults to 16 tokens —
 small enough that a slot wastes < 1 page of KV on average at release,
 large enough that the (page_size, hd) kernel tile fills a TPU sublane
@@ -42,14 +54,40 @@ per-(page, head, token) fp32 scale pages mirroring the dense int8 cache.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models.config import ModelConfig
 
 TRASH_PAGE = 0
+
+
+POOL_PREFIXES = ("pages_", "state_")
+
+
+def pool_keys(cache: Dict) -> List[str]:
+    """The cache leaves that are global pools (page pools and per-slot
+    state pools): a view shares them, a step hands back their updates."""
+    return [k for k in cache if k.startswith(POOL_PREFIXES)]
+
+
+def pool_view(cache: Dict, rows) -> Dict:
+    """The pools of ``cache`` plus the block-table rows and state slots of
+    the batch ``rows`` (an index array of slots)."""
+    view = {k: cache[k] for k in pool_keys(cache)}
+    view["block_tables"] = cache["block_tables"][rows]
+    if "slot_rows" in cache:
+        view["slot_rows"] = jnp.asarray(rows, jnp.int32)
+    return view
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _zero_row(pool, slot):
+    return pool.at[slot].set(0)
 
 
 class PagesExhausted(RuntimeError):
@@ -94,6 +132,7 @@ class PagedKVCache:
         self._tables = np.full((max_slots, self.blocks_per_slot), TRASH_PAGE,
                                np.int32)
         self._n_blocks = np.zeros((max_slots,), np.int32)
+        self.state_resets = 0       # slot state rows zeroed on admission
 
     # -- device-side pool construction ---------------------------------
     def init_cache(self) -> Dict:
@@ -104,12 +143,43 @@ class PagedKVCache:
         shape = (self.n_pages, cfg.n_kv_heads, self.page_size, cfg.hd)
         cache: Dict = {"len": jnp.zeros((self.max_slots,), jnp.int32),
                        "block_tables": self.device_block_tables()}
+        kinds = cfg.layer_kinds()
+        if cfg.block_pattern:
+            if q8:
+                raise NotImplementedError(
+                    f"{cfg.name}: int8 pages are not served for "
+                    "block-pattern hybrids")
+            cache["slot_rows"] = jnp.arange(self.max_slots,
+                                              dtype=jnp.int32)
         for l in range(cfg.n_layers):
+            if cfg.block_pattern and kinds[l] == "mamba":
+                cache[f"state_conv{l}"] = jnp.zeros(
+                    (self.max_slots, cfg.ssm_conv - 1, cfg.ssm_conv_dim),
+                    jnp.float32)
+                cache[f"state_ssm{l}"] = jnp.zeros(
+                    (self.max_slots, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_state), jnp.float32)
+                continue
+            if cfg.block_pattern and kinds[l] != "attn":
+                continue
             cache[f"pages_k{l}"] = jnp.zeros(shape, dt)
             cache[f"pages_v{l}"] = jnp.zeros(shape, dt)
             if q8:
                 cache[f"pages_ks{l}"] = jnp.zeros(shape[:3], jnp.float32)
                 cache[f"pages_vs{l}"] = jnp.zeros(shape[:3], jnp.float32)
+        return cache
+
+    def reset_state(self, cache: Dict, slot: int) -> Dict:
+        """Zero ``slot``'s row of every per-slot state pool — called when
+        the slot admits a sequence (fresh or recompute resume), so no
+        state of the slot's last occupant leaks into it.  In place (the
+        pool is donated)."""
+        cache = dict(cache)
+        idx = jnp.asarray([slot], jnp.int32)
+        for k in pool_keys(cache):
+            if k.startswith("state_"):
+                cache[k] = _zero_row(cache[k], idx)
+        self.state_resets += 1
         return cache
 
     def device_block_tables(self) -> jnp.ndarray:
@@ -361,6 +431,7 @@ class PagedKVCache:
             "mapped_pages": referenced,
             "pages_leaked": self.usable_pages - len(self._free) - referenced,
             "refcount_max": self._refcount_max,
+            "state_resets": self.state_resets,
         }
 
     def close(self) -> Dict:
@@ -383,8 +454,9 @@ def slot_view(cache: Dict, slot: int, length: int = 0) -> Dict:
     block-table row and length are sliced — no buffer copies.
     ``length`` is the slot's already-materialized KV length (non-zero when
     continuing a chunked prefill mid-prompt)."""
-    one = {k: v for k, v in cache.items()
-           if k.startswith("pages_")}
+    one = {k: cache[k] for k in pool_keys(cache)}
     one["block_tables"] = cache["block_tables"][slot:slot + 1]
+    if "slot_rows" in cache:
+        one["slot_rows"] = jnp.asarray([slot], jnp.int32)
     one["len"] = jnp.asarray(length, jnp.int32).reshape(())
     return one
