@@ -44,6 +44,17 @@ device queue (JAX async dispatch).  On a TPU the device is the chip and
 zero-copies ``device_put``; the mechanism — ordering, ring reuse,
 prefetch, correctness — is the same, and per-stream busy seconds are
 measured for the Table-2 style breakdown either way.
+
+The transfer runs one linear ahead of the serving thread.  The engine
+records the order in which its first pass calls the linears that have a
+device share, wrapping from the last to the first; from then on each
+such linear, once its own shard is in hand, sends the next one's DMA
+(acquire its pinned slot, then transfer) to the transfer thread, so the
+link runs while the serving thread moves activations, dispatches, and
+does everything between linears.  At most one shard is in flight beyond
+the linear being computed.  A call out of the predicted order is a miss:
+its shard is fetched on demand and the one in flight waits for its own
+call.  Weights do not depend on the input, so neither changes a result.
 """
 
 from __future__ import annotations
@@ -51,8 +62,8 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional, Sequence
+from concurrent.futures import Future, ThreadPoolExecutor
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +92,8 @@ class StreamStats:
     trans: float = 0.0         # host->device transfer seconds
     dev: float = 0.0           # device matmul seconds
     wall: float = 0.0          # end-to-end engine-active seconds
+    transfers_ahead: int = 0       # shard DMAs issued one linear ahead
+    transfers_on_demand: int = 0   # shard DMAs issued by their own linear
 
     def utilization(self) -> Dict[str, float]:
         w = max(self.wall, 1e-12)
@@ -95,7 +108,11 @@ class StreamStats:
                            pin=self.pin + other.pin,
                            trans=self.trans + other.trans,
                            dev=self.dev + other.dev,
-                           wall=max(self.wall, other.wall))
+                           wall=max(self.wall, other.wall),
+                           transfers_ahead=(self.transfers_ahead
+                                            + other.transfers_ahead),
+                           transfers_on_demand=(self.transfers_on_demand
+                                                + other.transfers_on_demand))
 
 
 def engine_matmul(x, w):
@@ -211,6 +228,18 @@ class HeteGenEngine:
         self._next_in_group = plan_prefetch_order(
             [n for n in self.order if n in stage_src], groups)
 
+        # look-ahead transfer: the first pass's call order of the linears
+        # with a device share, closed into a ring on its first repeat or
+        # at the next step boundary (:meth:`warm_prefetch`), and the one
+        # shard in flight beyond the current linear
+        self._calls: Dict[str, None] = {}
+        self._next_call: Optional[Dict[str, str]] = None
+        self._ahead: Optional[Tuple[str, Future]] = None
+        # jax's CPU backend zero-copies device_put, so a slot there stays
+        # held until the device matmul has read it; any other device has
+        # its own copy once the DMA completes
+        self._release_on_dma = self.device.platform != "cpu"
+
         self._cpu_pool = ThreadPoolExecutor(max_workers=1,
                                             thread_name_prefix="hostgemm")
         self._trans_pool = ThreadPoolExecutor(max_workers=1,
@@ -222,7 +251,10 @@ class HeteGenEngine:
 
     # ------------------------------------------------------------------
     def warm_prefetch(self) -> None:
-        """Stage the first module of each group before the step begins."""
+        """Stage the first module of each group before the step begins,
+        and, once the call order is known, send the DMA of the step's
+        first linear ahead.  Called between steps, it closes the first
+        pass's call order."""
         if self.manager is None:
             return
         seen = set()
@@ -233,6 +265,65 @@ class HeteGenEngine:
                 if p.group not in seen:
                     self.manager.prefetch(name)
                     seen.add(p.group)
+        if self._next_call is None and self._calls:
+            self._close_order()
+        if self._next_call is not None:
+            self._issue_ahead(next(iter(self._calls)))
+
+    def _close_order(self) -> None:
+        """Fix the first pass's call order, the last linear followed by
+        the first."""
+        calls = list(self._calls)
+        self._next_call = dict(zip(calls, calls[1:] + calls[:1]))
+
+    def _learn_order(self, name: str) -> None:
+        """Record ``name`` in the first pass's call order; the first
+        linear called a second time closes it."""
+        if self._next_call is not None:
+            return
+        if name in self._calls:
+            self._close_order()
+        else:
+            self._calls[name] = None
+
+    def _issue_ahead(self, name: str) -> None:
+        """Send ``name``'s DMA to the transfer thread unless a shard is
+        already in flight beyond the current linear."""
+        if self._ahead is not None:
+            return
+        fut = self._trans_pool.submit(self._fetch, name, None)
+        with self._lock:
+            self.stats.transfers_ahead += 1
+            self._ahead = (name, fut)
+
+    def _shard(self, name: str) -> Future:
+        """The future of ``name``'s shard: the one in flight if it was
+        issued ahead for ``name``, else a DMA issued now, on demand."""
+        if self._ahead is not None and self._ahead[0] == name:
+            with self._lock:
+                fut, self._ahead = self._ahead[1], None
+            return fut
+        buf = self.manager.acquire(name)
+        with self._lock:
+            self.stats.transfers_on_demand += 1
+        return self._trans_pool.submit(self._fetch, name, buf)
+
+    def _fetch(self, name: str, buf: Optional[Entry]
+               ) -> Tuple[Entry, Optional[int]]:
+        """The transfer task: ``name``'s shard on the device, and the pin
+        sequence number it was staged under.  ``buf`` None means the DMA
+        was issued ahead, so the task acquires the pinned slot itself."""
+        ahead = buf is None
+        if ahead:
+            buf = self.manager.acquire_ahead(name)
+        seq = self.manager.seq_of(name)
+        w_dev = self._transfer(buf, name, seq, ahead)
+        if self._release_on_dma:
+            # the slot is free for the pin thread to stage the linear
+            # after next while the serving thread computes
+            self.manager.release(name)
+            self.manager.prefetch(self._next_in_group.get(name))
+        return w_dev, seq
 
     def _host_matmul(self, x_np: np.ndarray, name: str) -> np.ndarray:
         w = self._host_part[name]
@@ -246,11 +337,12 @@ class HeteGenEngine:
                 self.stats.cpu += time.perf_counter() - t0
         return y
 
-    def _transfer(self, buf: Entry, name: str,
-                  seq: Optional[int]) -> Entry:
+    def _transfer(self, buf: Entry, name: str, seq: Optional[int],
+                  ahead: bool) -> Entry:
         parts = buf if isinstance(buf, tuple) else (buf,)
         wire = sum(p.nbytes for p in parts)
-        attrs = dict(bytes=wire, module=name, phase=self.trace_phase)
+        attrs = dict(bytes=wire, module=name, phase=self.trace_phase,
+                     ahead=ahead)
         if seq is not None:
             attrs["seq"] = seq
         fp = self._fp_shard_bytes.get(name)
@@ -310,19 +402,20 @@ class HeteGenEngine:
                     x_np = np.asarray(x)
                 host_fut = self._cpu_pool.submit(self._host_matmul, x_np, name)
 
-            # 3. device share: acquire pinned buffer, DMA, matmul.  The slot
-            # is released only after the device matmul finished: on a real
-            # TPU the DMA copy would suffice, but jax's CPU backend
-            # zero-copies device_put, so the device read must complete
-            # before the slot can be re-staged.
+            # 3. device share: its shard (in flight if issued ahead, else
+            # acquired and sent now), then the next linear's DMA ahead,
+            # then the matmul.  On the CPU backend the slot is released
+            # only after the device matmul finished (zero-copy
+            # device_put); elsewhere the transfer task released it.
             y_dev = None
             if cols > 0:
-                buf = self.manager.acquire(name)
-                seq = self.manager.seq_of(name)
-                w_fut = self._trans_pool.submit(self._transfer, buf, name,
-                                                seq)
+                self._learn_order(name)
+                w_fut = self._shard(name)
+                nxt = (self._next_call or {}).get(name)
+                if nxt is not None and nxt != name:
+                    self._issue_ahead(nxt)
                 with self._wait("transfer", name):
-                    w_dev = w_fut.result()
+                    w_dev, seq = w_fut.result()
                 with self.tracer.span(name, track="device", module=name,
                                       phase=self.trace_phase, seq=seq):
                     t0 = time.perf_counter()
@@ -337,7 +430,8 @@ class HeteGenEngine:
                         y_dev.block_until_ready()
                     with self._lock:
                         self.stats.dev += time.perf_counter() - t0
-                self.manager.release(name)
+                if not self._release_on_dma:
+                    self.manager.release(name)
 
             # 4. combine
             if host_fut is None:
@@ -389,5 +483,7 @@ class HeteGenEngine:
     def close(self) -> None:
         self._cpu_pool.shutdown(wait=True)
         self._trans_pool.shutdown(wait=True)
+        with self._lock:
+            self._ahead = None       # the in-flight shard's device copy
         if self.manager is not None:
             self.manager.shutdown()

@@ -225,19 +225,33 @@ class AsyncParamManager:
         caller's wait for its staged weights), with ``miss=True`` when
         the prefetch never happened and the pin ran synchronously.
         """
-        ring = self.rings[self.groups[name]]
         with self.tracer.span("pin", track="wait", module=name,
                               phase=self.trace_phase) as sp:
-            with ring.lock:
-                slot = ring.slot_for(name)
-                if slot is None:
-                    sp.set(miss=True)
-                    slot = self._claim_slot(ring, name)
-                    self._submit_pin(slot, name)
-                    self.tracer.event("pin_sync", track="pin", module=name,
-                                      phase=self.trace_phase)
-                slot.in_use = True
-            return slot.ready.result()
+            ready, missed = self._take(name)
+            if missed:
+                sp.set(miss=True)
+            return ready.result()
+
+    def acquire_ahead(self, name: str) -> Entry:
+        """:meth:`acquire` for a caller that is not the serving thread
+        (the engine's transfer task, fetching a shard ahead of its
+        linear): the same slot and weights, and no ``wait`` span."""
+        return self._take(name)[0].result()
+
+    def _take(self, name: str) -> Tuple[Future, bool]:
+        """Mark ``name``'s slot in use, pinning it now if it is not
+        staged; its staging future, and whether the pin was a miss."""
+        ring = self.rings[self.groups[name]]
+        with ring.lock:
+            slot = ring.slot_for(name)
+            missed = slot is None
+            if missed:
+                slot = self._claim_slot(ring, name)
+                self._submit_pin(slot, name)
+                self.tracer.event("pin_sync", track="pin", module=name,
+                                  phase=self.trace_phase)
+            slot.in_use = True
+            return slot.ready, missed
 
     def _claim_slot(self, ring: GroupRing, name: str) -> PinSlot:
         """A slot to pin ``name`` into now: a free one, else a staged,
